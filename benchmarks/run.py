@@ -99,6 +99,9 @@ def main() -> None:
         streaming_track,
         svd_comparison,
     )
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     table = {
         "hadamard": hadamard.run,

@@ -353,6 +353,7 @@ def measure(
     import jax
     import jax.numpy as jnp
 
+    from repro.api.dispatch import fit_chain_bt
     from repro.kernels.chain import DEFAULT_BT
 
     global _MEASURING
@@ -362,8 +363,15 @@ def measure(
     _MEASURING = True
     try:
         for backend in op.feasible_backends():
+            # each candidate as dispatch would fit it, so every row times
+            # a distinct tile under the label it really ran at
             tiles = (
-                sorted(set(bt_candidates()) | {DEFAULT_BT})
+                sorted(
+                    {
+                        fit_chain_bt(op, t, x.dtype, op.quant_info()[0], grad)[0]
+                        for t in set(bt_candidates()) | {DEFAULT_BT}
+                    }
+                )
                 if backend in ("fused", "fused_sharded") and use_kernel
                 else (DEFAULT_BT,)
             )
@@ -443,11 +451,11 @@ def ensure_measured(
     re-entrantly from a measurement apply, or for a non-leaf operator.
     Callers gate on the *mode* — this function only guards feasibility.
     """
-    import jax
+    from repro.core.eager import is_eager
 
     if _MEASURING or op.kind != "leaf":
         return None
-    if not jax.core.trace_state_clean() or isinstance(x, jax.core.Tracer):
+    if not is_eager(x):
         return None
     key = key_for_op(
         op, batch=batch, dtype=dtype, grad=grad, mesh_shape=mesh_shape

@@ -110,8 +110,9 @@ class DispatchReport:
     # (autotune table hit — est_us are then real host µs and `backend` is
     # the measured-fastest feasible path; see repro.api.autotune)
     source: str = "model"
-    # the chain kernels' batch tile this decision priced/selected
-    # (caller-forced > autotuned winner > DEFAULT_BT)
+    # the chain kernels' batch tile this decision priced and the apply
+    # runs: the requested tile (caller-forced > autotuned winner >
+    # DEFAULT_BT) halved until the kernels' VMEM footprint fits
     bt: int = DEFAULT_BT
     # the weight-stream bytes the structured backends were priced at:
     # values bytes (post-quantization — 1 byte/value for int8/fp8 payloads)
@@ -496,6 +497,11 @@ def dispatch(
         int(entry["bt"]) if entry is not None and entry.get("bt") else DEFAULT_BT
     )
     values_dtype, scales_bytes = op.quant_info()
+    eff_bt, unfit = fit_chain_bt(op, eff_bt, dtype, values_dtype, grad)
+    if "fused" not in cand:
+        unfit = None
+    if unfit is not None and requested != "fused":
+        cand = tuple(b for b in cand if b != "fused")
     report = choose_backend(
         batch=batch,
         shape=op.shape,
@@ -540,6 +546,10 @@ def dispatch(
                     f"weight_bytes={report.weight_bytes})"
                 ),
             )
+    if unfit is not None:
+        report = dataclasses.replace(
+            report, reason=f"{report.reason}; fused ruled out: {unfit}"
+        )
     if requested != "auto":
         report = dataclasses.replace(
             report,
@@ -548,3 +558,37 @@ def dispatch(
                    f"{report.backend}: {report.reason})",
         )
     return _record(report) if record else report
+
+
+def fit_chain_bt(
+    op, bt: int, dtype, values_dtype=None, grad: bool = False
+) -> tuple[int, str | None]:
+    """The chain kernels' batch tile for ``op`` and, when none fits, why.
+
+    Returns the largest power-of-two divisor of ``bt`` whose forward VMEM
+    footprint (and, with ``grad``, the backward's) fits the kernels'
+    budgets — the one tile every fused launch of the apply runs at — or
+    ``(bt, reason)`` when no tile does, computed from shapes so an unfit
+    chain is priced without ``fused`` instead of failing at compile time.
+    Operators with no chain plan keep ``bt``."""
+    from repro.kernels import chain as kchain
+    from repro.kernels import chain_bwd as kbwd
+
+    plan = op.chain_plan()
+    if plan is None:
+        return bt, None
+    elt = jnp.dtype(dtype).itemsize
+    v_elt = jnp.dtype(values_dtype).itemsize if values_dtype else elt
+    quant = values_dtype is not None
+    fitted = kchain.fit_bt(
+        lambda t: kchain.fwd_vmem_bytes(plan, t, elt, v_elt, quant), bt
+    )
+    if fitted is not None and grad:
+        # wgrad's footprint contains dgrad's: one fit covers both kernels
+        fitted = kbwd.fit_bt(plan, fitted, elt, wgrad=True, quant=quant)
+    if fitted is not None:
+        return fitted, None
+    why = kchain.fwd_infeasible(plan, elt, v_elt, quant)
+    if why is None and grad:
+        why = kbwd.bwd_infeasible(plan, elt, quant)
+    return bt, why or f"no power-of-two tile dividing bt={bt} fits VMEM"

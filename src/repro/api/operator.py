@@ -51,12 +51,15 @@ import numpy as np
 
 from repro.core.compress import (
     BlockFaust,
+    ChainPlan,
     PackedChain,
     _faust_to_blockfaust,
+    chain_plan,
     expand_scales,
     pack_chain,
     unpack_chain,
 )
+from repro.core.eager import cached
 from repro.core.faust import Faust
 
 Array = jax.Array
@@ -93,80 +96,30 @@ def _conj_rep(rep):
     )
 
 
-# Eager-mode fused applies would otherwise re-flatten the whole chain per
-# call; keyed by factor identity (a weakref guards id() reuse) and bypassed
-# under tracing (caching tracers would leak them out of their trace).
-_PACK_CACHE: dict[int, tuple] = {}
-_PACK_CACHE_MAX = 64
+# Eager applies would otherwise re-pack (or re-slice) the whole chain per
+# call; one cache per conversion, keyed by the source rep's identity.
+_PACK_CACHE: dict[tuple, tuple] = {}
+_UNPACK_CACHE: dict[tuple, tuple] = {}
+_CACHE_MAX = 64
 
 
 def _cached_pack(bf: BlockFaust) -> "PackedChain":
-    # Under ANY active trace the pack's concatenates bind into that trace
-    # and return tracers even when every input is a closed-over constant —
-    # caching those would leak them into later traces (observed as an
-    # UnexpectedTracerError when a second jit reused the entry).  Checking
-    # the inputs alone is therefore not enough; bail on a dirty trace state.
-    if not jax.core.trace_state_clean() or isinstance(
-        bf.lam, jax.core.Tracer
-    ) or any(isinstance(f.values, jax.core.Tracer) for f in bf.factors):
-        return pack_chain(bf)  # trace-time: packing is staged, not run
-    import weakref
-
-    ent = _PACK_CACHE.get(id(bf))
-    if ent is not None and ent[0]() is bf:
-        return ent[1]
-    pc = pack_chain(bf)
-    if len(_PACK_CACHE) >= _PACK_CACHE_MAX:
-        _PACK_CACHE.pop(next(iter(_PACK_CACHE)))
-    _PACK_CACHE[id(bf)] = (weakref.ref(bf), pc)
-    return pc
+    return cached(
+        _PACK_CACHE, _CACHE_MAX, bf, (), lambda: pack_chain(bf),
+        bf.lam, *(f.values for f in bf.factors),
+    )
 
 
-def _cached_unpack(pc: PackedChain) -> BlockFaust:
+def _cached_unpack(pc: PackedChain, dequantize: bool = True) -> BlockFaust:
     """Eager unpack cache (mirrors :func:`_cached_pack`): a sharded packed
     leaf would otherwise re-slice its factors — and re-key the shard-plan
-    cache — on every apply."""
-    if not jax.core.trace_state_clean() or isinstance(
-        pc.values, jax.core.Tracer
-    ):
-        return unpack_chain(pc)
-    import weakref
-
-    ent = _UNPACK_CACHE.get(id(pc))
-    if ent is not None and ent[0]() is pc:
-        return ent[1]
-    bf = unpack_chain(pc)
-    if len(_UNPACK_CACHE) >= _PACK_CACHE_MAX:
-        _UNPACK_CACHE.pop(next(iter(_UNPACK_CACHE)))
-    _UNPACK_CACHE[id(pc)] = (weakref.ref(pc), bf)
-    return bf
-
-
-_UNPACK_CACHE: dict[int, tuple] = {}
-
-
-def _cached_unpack_raw(pc: PackedChain) -> BlockFaust:
-    """Unpack a *quantized* chain keeping the int8/fp8 codes in the factor
-    values (``dequantize=False``) — the sharded path dequantizes in-kernel
-    against the separately-threaded scales, so handing it f32 factors would
-    double the weight bytes it exists to halve."""
-    if not jax.core.trace_state_clean() or isinstance(
-        pc.values, jax.core.Tracer
-    ):
-        return unpack_chain(pc, dequantize=False)
-    import weakref
-
-    ent = _UNPACK_RAW_CACHE.get(id(pc))
-    if ent is not None and ent[0]() is pc:
-        return ent[1]
-    bf = unpack_chain(pc, dequantize=False)
-    if len(_UNPACK_RAW_CACHE) >= _PACK_CACHE_MAX:
-        _UNPACK_RAW_CACHE.pop(next(iter(_UNPACK_RAW_CACHE)))
-    _UNPACK_RAW_CACHE[id(pc)] = (weakref.ref(pc), bf)
-    return bf
-
-
-_UNPACK_RAW_CACHE: dict[int, tuple] = {}
+    cache — on every apply.  ``dequantize=False`` keeps a quantized
+    chain's int8/fp8 codes in the factor values: the sharded path
+    dequantizes in-kernel against the separately-threaded scales."""
+    return cached(
+        _UNPACK_CACHE, _CACHE_MAX, pc, (dequantize,),
+        lambda: unpack_chain(pc, dequantize=dequantize), pc.values,
+    )
 
 
 def _shard_view(rep) -> tuple[BlockFaust, "Array | None"]:
@@ -177,7 +130,7 @@ def _shard_view(rep) -> tuple[BlockFaust, "Array | None"]:
         return rep, None
     if rep.qscheme is not None:
         return (
-            _cached_unpack_raw(rep),
+            _cached_unpack(rep, dequantize=False),
             expand_scales(rep.scales, rep.plan.block),
         )
     return _cached_unpack(rep), None
@@ -197,10 +150,12 @@ def _under_ad(*trees) -> bool:
     Conversely a pure forward-mode ``jax.jvp`` also carries JVPTracers
     and is priced as training (whether a transpose follows is unknowable
     at trace time) — pass ``grad=False`` for jvp-only workloads."""
-    from jax.interpreters import ad
+    # jax.grad/vjp trace with LinearizeTracer (JAX ≥ 0.7), jvp with
+    # JVPTracer; only the former has no public alias
+    from jax._src.interpreters import ad
 
     return any(
-        isinstance(leaf, ad.JVPTracer)
+        isinstance(leaf, (ad.JVPTracer, ad.LinearizeTracer))
         for tree in trees
         for leaf in jax.tree_util.tree_leaves(tree)
     )
@@ -208,10 +163,11 @@ def _under_ad(*trees) -> bool:
 
 def _degraded_on() -> bool:
     """Whether degraded-mode dispatch (auto-backend failure → one priced
-    demotion to a reference path) is enabled — ``REPRO_DEGRADED``,
-    default on; ``0``/``off`` makes auto applies fail loud instead."""
+    demotion to a reference path) is enabled.  Opt-in with
+    ``REPRO_DEGRADED=1``: unset, a failing auto-chosen backend raises, so
+    a broken kernel can never be replaced by a slower path unnoticed."""
     v = os.environ.get("REPRO_DEGRADED", "").strip().lower()
-    return v not in ("0", "off", "false", "no")
+    return v in ("1", "on", "true", "yes")
 
 
 def _fusable(bf: BlockFaust) -> bool:
@@ -597,13 +553,13 @@ class FaustOp:
                 shard_plan, bf_sharded, shard_scales,
             )
         except Exception as exc:  # noqa: BLE001 — degraded-mode boundary
-            # Degraded-mode dispatch (ISSUE 10): an auto-chosen backend
-            # that raises (broken lowering, VMEM overrun, driver state)
-            # demotes ONCE down the priced ladder to a reference path
-            # (bsr/dense), quarantining the failing (signature, backend)
-            # for the session so later auto dispatches skip it up front.
-            # Forced backends re-raise: measurement sweeps and tests rely
-            # on forced failures staying loud.  Only trace/eager-visible
+            # Degraded-mode dispatch (opt-in, ``REPRO_DEGRADED=1``): an
+            # auto-chosen backend that raises demotes ONCE down the priced
+            # ladder to a reference path (bsr/dense), quarantining the
+            # failing (signature, backend) for the session so later auto
+            # dispatches skip it up front.  Off by default: a kernel that
+            # fails must fail the apply, not hand it silently to a slower
+            # path.  Forced backends always re-raise.  Only trace/eager-visible
             # failures are catchable — a runtime abort inside a compiled
             # step is jax's to surface.
             ladder = tuple(
@@ -702,6 +658,16 @@ class FaustOp:
         if isinstance(self.rep, PackedChain) or _fusable(self.rep):
             return ("dense", "bsr", "fused") + sharded
         return ("dense", "bsr") + sharded
+
+    def chain_plan(self) -> "ChainPlan | None":
+        """The fused kernels' :class:`ChainPlan` for this leaf (shapes
+        only — nothing is packed), or None when ``fused`` is not among
+        :meth:`feasible_backends`."""
+        if "fused" not in self.feasible_backends():
+            return None
+        if isinstance(self.rep, PackedChain):
+            return self.rep.plan
+        return chain_plan(self.rep)
 
     def quant_info(self) -> tuple[str | None, int]:
         """``(values_dtype, scales_bytes)`` for the dispatch byte model: the

@@ -183,10 +183,12 @@ class ChainPlan:
         return self.offsets[-1]
 
     @property
-    def max_blocks(self) -> int:
-        """Widest activation (in blocks) anywhere along the chain — sizes the
-        kernel's ping-pong VMEM scratch."""
-        return max(max(self.in_blocks), max(self.out_blocks))
+    def act_blocks(self) -> int:
+        """Widest activation (in blocks) a chain kernel keeps resident in
+        VMEM: the chain input and every inner boundary.  The last factor's
+        output streams to HBM one block at a time, so the output width
+        never sizes scratch."""
+        return max(self.in_blocks)
 
     @property
     def in_features(self) -> int:
@@ -356,6 +358,24 @@ def dequantize_chain(chain: PackedChain) -> PackedChain:
     return PackedChain(v, chain.in_idx, chain.lam, chain.plan)
 
 
+def chain_plan(bfaust: BlockFaust) -> ChainPlan:
+    """The :class:`ChainPlan` :func:`pack_chain` would build — shapes
+    only, no array is touched (callers must check packability first)."""
+    factors = bfaust.factors
+    offsets = [0]
+    for f in factors:
+        offsets.append(offsets[-1] + f.n_out_blocks * f.k)
+    return ChainPlan(
+        block=factors[0].bk,
+        in_blocks=tuple(f.n_in_blocks for f in factors),
+        out_blocks=tuple(f.n_out_blocks for f in factors),
+        k_blocks=tuple(f.k for f in factors),
+        offsets=tuple(offsets),
+        in_feats=tuple(f.in_features for f in factors),
+        out_feats=tuple(f.out_features for f in factors),
+    )
+
+
 def pack_chain(bfaust: BlockFaust) -> PackedChain:
     """Flatten a :class:`BlockFaust` into the fused-kernel layout.
 
@@ -379,18 +399,7 @@ def pack_chain(bfaust: BlockFaust) -> PackedChain:
                 f"{a.out_features}/{a.n_out_blocks} blocks → "
                 f"{b.in_features}/{b.n_in_blocks} blocks"
             )
-    offsets = [0]
-    for f in factors:
-        offsets.append(offsets[-1] + f.n_out_blocks * f.k)
-    plan = ChainPlan(
-        block=blk,
-        in_blocks=tuple(f.n_in_blocks for f in factors),
-        out_blocks=tuple(f.n_out_blocks for f in factors),
-        k_blocks=tuple(f.k for f in factors),
-        offsets=tuple(offsets),
-        in_feats=tuple(f.in_features for f in factors),
-        out_feats=tuple(f.out_features for f in factors),
-    )
+    plan = chain_plan(bfaust)
     values = jnp.concatenate([f.values.reshape(-1, blk, blk) for f in factors])
     in_idx = jnp.concatenate(
         [f.in_idx.reshape(-1).astype(jnp.int32) for f in factors]

@@ -22,7 +22,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -98,9 +97,9 @@ def pipeline_apply(
         P(None),  # microbatched input replicated along the pipeline axis
     )
     out_specs = P(None)
-    y = shard_map(
+    y = jax.shard_map(
         pp, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(stage_params, xm)
     return y.reshape(b, *x.shape[1:])
 

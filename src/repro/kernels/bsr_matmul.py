@@ -7,10 +7,11 @@ sparse factors to be *block* sparse (DESIGN.md §3). This kernel computes
 
 with a 3-D grid ``(batch tiles, output blocks, k)``:
 
-  * the block-column indices ``in_idx`` are **scalar-prefetched** so the
-    ``x`` BlockSpec index_map can steer the HBM→VMEM stream to fetch only
-    the K referenced input blocks per output block — the TPU analog of the
-    paper's "only touch the nonzeros";
+  * the block-column indices ``in_idx`` are **scalar-prefetched** (flattened
+    to ``(O·K,)``: SMEM pads the minor dim of a 2-D table to 128 lanes) so
+    the ``x`` BlockSpec index_map can steer the HBM→VMEM stream to fetch
+    only the K referenced input blocks per output block — the TPU analog of
+    the paper's "only touch the nonzeros";
   * a VMEM scratch accumulator carries the partial product across the k
     dimension (f32 accumulation regardless of input dtype);
   * block shapes are chosen by the caller; production sizes are MXU-aligned
@@ -39,10 +40,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.chain import dot_precision
+
 Array = jax.Array
 
 
-def _bsr_matmul_kernel(idx_ref, x_ref, v_ref, o_ref, acc_ref, *, n_k: int):
+def _bsr_matmul_kernel(idx_ref, x_ref, v_ref, o_ref, acc_ref, *, n_k: int, precision):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -52,6 +55,7 @@ def _bsr_matmul_kernel(idx_ref, x_ref, v_ref, o_ref, acc_ref, *, n_k: int):
     acc_ref[...] += jnp.dot(
         x_ref[...],
         v_ref[0, 0],
+        precision=precision,
         preferred_element_type=jnp.float32,
     )
 
@@ -77,13 +81,15 @@ def bsr_matmul(
     grid = (b // bt, o, k)
 
     return pl.pallas_call(
-        functools.partial(_bsr_matmul_kernel, n_k=k),
+        functools.partial(
+            _bsr_matmul_kernel, n_k=k, precision=dot_precision(x.dtype)
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
                 # x: batch tile  ×  the k-th referenced input block
-                pl.BlockSpec((bt, bk), lambda bi, oi, ki, idx: (bi, idx[oi, ki])),
+                pl.BlockSpec((bt, bk), lambda bi, oi, ki, idx: (bi, idx[oi * k + ki])),
                 # values: one (bk × bn) block per (o, k)
                 pl.BlockSpec((1, 1, bk, bn), lambda bi, oi, ki, idx: (oi, ki, 0, 0)),
             ],
@@ -92,4 +98,4 @@ def bsr_matmul(
         ),
         out_shape=jax.ShapeDtypeStruct((b, o * bn), x.dtype),
         interpret=interpret,
-    )(in_idx, x, values)
+    )(in_idx.reshape(-1), x, values)

@@ -34,10 +34,15 @@ Together: the whole chain backward is **≤ 2 launches** for any J (vs
 ~3·J), with weight traffic ``3·s_tot`` (dgrad stream + wgrad's two
 phases) and *no* per-boundary activation round-trips.  VMEM budget: the
 wgrad scratch holds every per-factor input activation
-(``Σ_j IB_j · bt · blk`` f32) plus the cotangent ping-pong, so wide
-chains shrink the batch tile automatically (:func:`fit_bt` halves ``bt``
-until the footprint fits — interpret mode never checks VMEM, real TPU
-does at compile time).
+(``Σ_j IB_j · bt · blk`` f32) plus the cotangent ping-pong, sized by the
+input and inner widths only — ``dy`` streams in one ``(bt, blk)`` block
+per last-factor step, so a vocabulary-wide output costs no VMEM.  Chains
+with wide inner widths run a smaller batch tile: dispatch halves ``bt``
+until the footprint fits (:func:`fit_bt` — interpret mode never checks
+VMEM, real TPU does at compile time) and the kernels check the tile they
+are given (:func:`check_bwd_bt`); a chain that fits no tile is refused
+from its shapes (:func:`bwd_infeasible`).  Step tables are
+scalar-prefetched flat, as in the forward.
 
 ``chain_bwd_ref`` is the step-exact jnp oracle (the old rematerializing
 walk) — the parity target for tests and the ``REPRO_CHAIN_BWD=ref``
@@ -46,7 +51,6 @@ escape hatch in ``kernels/ops.py``.
 from __future__ import annotations
 
 import functools
-import weakref
 
 import jax
 import jax.numpy as jnp
@@ -55,8 +59,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.compress import ChainPlan
+from repro.core.eager import cached
 from repro.kernels import ref as _ref
-from repro.kernels.chain import DEFAULT_BT
+from repro.kernels.chain import (
+    COMPILER_PARAMS,
+    DEFAULT_BT,
+    MIN_BT,
+    VMEM_BUDGET_BYTES,
+    dot_precision,
+)
+from repro.kernels.chain import fit_bt as _fit_bt
 
 Array = jax.Array
 
@@ -84,58 +96,96 @@ WGRAD_META_COLS = 7
 
 # Assembled (static ++ runtime in_idx) tables, keyed by the in_idx array
 # identity — repeated eager applies of the same operator do zero per-call
-# host work.  Bypassed under tracing (a cached tracer would leak out of
-# its trace); the per-plan static halves below stay lru-cached either way.
+# host work.  Rebuilt under tracing (``repro.core.eager``); the per-plan
+# static halves below stay lru-cached either way.
 _TABLE_CACHE: dict[tuple, tuple] = {}
 _TABLE_CACHE_MAX = 256
 
 
 def cached_table(plan: ChainPlan, in_idx: Array, tag: str, build) -> Array:
-    """Cache ``build()`` per ``(in_idx identity, plan, tag)`` (weakref-guarded
-    against id() reuse); assemble inline under tracing."""
-    if not jax.core.trace_state_clean() or isinstance(in_idx, jax.core.Tracer):
-        return build()
-    key = (id(in_idx), plan, tag)
-    ent = _TABLE_CACHE.get(key)
-    if ent is not None and ent[0]() is in_idx:
-        return ent[1]
-    table = build()
-    if len(_TABLE_CACHE) >= _TABLE_CACHE_MAX:
-        _TABLE_CACHE.pop(next(iter(_TABLE_CACHE)))
-    _TABLE_CACHE[key] = (weakref.ref(in_idx), table)
-    return table
+    """Cache ``build()`` per ``(in_idx identity, plan, tag)``; assemble
+    inline under tracing."""
+    return cached(_TABLE_CACHE, _TABLE_CACHE_MAX, in_idx, (plan, tag), build)
 
 
 def _ncols(plan: ChainPlan, j: int, o: np.ndarray) -> np.ndarray:
     return np.minimum(plan.block, plan.out_feats[j] - o * plan.block)
 
 
-# VMEM budget for a backward kernel's scratch + resident input tiles.
-# Real-TPU VMEM is ~16 MiB/core; leave headroom for Mosaic's own double
-# buffering of the streamed value blocks.
-_VMEM_BUDGET_BYTES = 12 * 2**20
+# Footprint the batch-tile search fits into (module-level so tests can
+# shrink it); Mosaic gets ``chain.VMEM_LIMIT_BYTES`` as its scoped limit.
+_VMEM_BUDGET_BYTES = VMEM_BUDGET_BYTES
 
 
-def fit_bt(plan: ChainPlan, bt: int, elt: int, *, wgrad: bool) -> int:
+def bwd_vmem_bytes(
+    plan: ChainPlan, bt: int, elt: int, *, wgrad: bool, quant: bool = False
+) -> int:
+    """VMEM footprint of :func:`chain_dgrad` (``wgrad=False``) or
+    :func:`chain_wgrad` at batch tile ``bt``; ``elt`` is the activation /
+    cotangent itemsize.  Value blocks are counted at 4 bytes (an upper
+    bound for every stored dtype), a quantized chain's scale row
+    sublane-padded to 8.  The chain-end cotangent streams one ``(bt, blk)``
+    block per step, so the output width never enters."""
+    blk = plan.block
+    in_w = plan.in_blocks[0] * blk
+    streams = 2 * bt * blk * elt + 2 * blk * blk * 4  # dy block, value block
+    if quant:
+        streams += 2 * 8 * blk * 4  # scale row
+    cot = 2 * plan.act_blocks * bt * blk * 4  # f32 cotangent ping-pong
+    if not wgrad:
+        return streams + 2 * bt * in_w * elt + cot  # + dx tile
+    acts = sum(plan.in_blocks) * bt * blk * 4  # every factor's input
+    return (
+        streams + 2 * bt * in_w * elt  # x tile
+        + 2 * blk * blk * 4  # dvalues block
+        + acts + cot + bt * blk * 4  # + recompute accumulator
+    )
+
+
+def fit_bt(
+    plan: ChainPlan, bt: int, elt: int, *, wgrad: bool, quant: bool = False
+) -> int | None:
     """Largest power-of-two divisor of ``bt`` (≥ 8) whose backward-kernel
-    footprint fits the VMEM budget.  The forward pads the batch to a
-    multiple of ``bt``, so any divisor still tiles it exactly.  Unlike the
+    footprint fits the VMEM budget, or None when none does.  Unlike the
     forward kernel (one ping-pong pair in x dtype), the backward holds f32
     cotangent slabs — and wgrad additionally every factor's input
-    activation plus both edge tiles — so wide chains (large
-    ``max_blocks``) must shrink the batch tile instead of overflowing
-    VMEM at kernel compile time."""
-    blk = plan.block
-    # resident edge tiles: dy in + dx out (dgrad) / x + dy in (wgrad)
-    edge_blocks = plan.in_blocks[0] + plan.out_blocks[-1]
-    while bt > 8:
-        scratch = 2 * plan.max_blocks * bt * blk * 4  # cotangent ping-pong
-        if wgrad:
-            scratch += (sum(plan.in_blocks) + 1) * bt * blk * 4
-        if scratch + bt * edge_blocks * blk * elt <= _VMEM_BUDGET_BYTES:
-            break
-        bt //= 2
-    return max(bt, 8)
+    activation — so chains with wide inner widths shrink the batch tile
+    instead of overflowing VMEM at kernel compile time."""
+    return _fit_bt(
+        lambda t: bwd_vmem_bytes(plan, t, elt, wgrad=wgrad, quant=quant),
+        bt,
+        _VMEM_BUDGET_BYTES,
+    )
+
+
+def bwd_infeasible(plan: ChainPlan, elt: int, quant: bool = False) -> str | None:
+    """Why no batch tile fits the backward kernels' VMEM budget (None when
+    one does) — shape-computed, like :func:`repro.kernels.chain.fwd_infeasible`."""
+    need = bwd_vmem_bytes(plan, MIN_BT, elt, wgrad=True, quant=quant)
+    if need <= _VMEM_BUDGET_BYTES:
+        return None
+    return (
+        f"fused backward needs {need} B of VMEM at bt={MIN_BT} "
+        f"(input {plan.in_blocks[0]} blocks, activations {sum(plan.in_blocks)} "
+        f"blocks of {plan.block}) > budget {_VMEM_BUDGET_BYTES} B"
+    )
+
+
+def check_bwd_bt(
+    plan: ChainPlan, bt: int, elt: int, *, wgrad: bool, quant: bool = False
+) -> None:
+    """Raise unless the backward kernel's footprint at ``bt`` fits the
+    budget.  Dispatch fits the tile for forward and backward together when
+    it sees the apply is differentiated; a ``grad(jit(f))`` trace hides
+    that, and such callers pass ``apply(..., grad=True)``."""
+    need = bwd_vmem_bytes(plan, bt, elt, wgrad=wgrad, quant=quant)
+    if need > _VMEM_BUDGET_BYTES:
+        raise ValueError(
+            bwd_infeasible(plan, elt, quant)
+            or f"fused {'wgrad' if wgrad else 'dgrad'} needs {need} B of VMEM "
+            f"at bt={bt} > budget {_VMEM_BUDGET_BYTES} B; dispatch with "
+            f"grad=True fits the tile for the backward"
+        )
 
 
 @functools.lru_cache(maxsize=64)
@@ -234,43 +284,50 @@ def wgrad_meta(plan: ChainPlan, in_idx: Array) -> Array:
 
 
 def _dgrad_kernel(
-    meta_ref, dy_ref, v_ref, *refs, n_out_last, n_in0, blk, n_steps,
-    out_par, quant,
+    meta_ref, dy_ref, v_ref, *refs, n_last, n_in0, blk, n_steps, out_par,
+    quant, precision,
 ):
     # Quantized chains stream the per-step (1, blk) f32 scale row next to
-    # the value block and dequantize in VMEM; scaling the block's *rows*
-    # commutes with the transposed read (g @ (diag(s)·Q)ᵀ = (g @ Qᵀ)·diag(s)
-    # applied columnwise), so dequant-then-dot is exact here too.
+    # the value block.  Scaling the block's *rows* commutes with the
+    # transposed read, g @ (diag(s)·Q)ᵀ = (g @ Qᵀ)·diag(s), so the scale
+    # multiplies the product's columns and Q enters the dot as stored.
     if quant:
         s_ref, o_ref, cot_ref = refs
     else:
         o_ref, cot_ref = refs
     t = pl.program_id(1)
-    dst = meta_ref[t, 0]
-    src = meta_ref[t, 1]
-    par = meta_ref[t, 2]
+    row = t * DGRAD_META_COLS
+    dst = meta_ref[row]
+    src = meta_ref[row + 1]
+    par = meta_ref[row + 2]
+    v = v_ref[0]
+    if quant:
+        v = v.astype(jnp.float32)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (dy_ref.shape[0], blk), 1)
 
-    @pl.when(t == 0)
-    def _load_dy():
-        # Stage the dy tile into the chain-end cotangent buffer (parity 0
-        # by the (J-1-j)%2 convention), block-major, f32.
-        for b in range(n_out_last):
-            cot_ref[0, b] = dy_ref[:, b * blk : (b + 1) * blk].astype(jnp.float32)
-
-    @pl.when(meta_ref[t, 3] == 1)
+    @pl.when(meta_ref[row + 3] == 1)
     def _open_factor():
         # Scatter target of a fresh factor: blocks never written must read 0.
         cot_ref[1 - par] = jnp.zeros(cot_ref.shape[1:], cot_ref.dtype)
 
-    cols = jax.lax.broadcasted_iota(jnp.int32, cot_ref.shape[2:], 1)
-    g = jnp.where(cols < meta_ref[t, 4], cot_ref[par, src], 0.0)
-    v = v_ref[0]
-    if quant:
-        v = v.astype(jnp.float32) * s_ref[0][:, None]
-    # g @ F[s]ᵀ — the transposed block read straight off the packed layout
-    cot_ref[1 - par, dst] += jax.lax.dot_general(
-        g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    def scatter(g):
+        # g @ F[s]ᵀ — the transposed block read straight off the packed layout
+        g = jnp.where(cols < meta_ref[row + 4], g, 0.0)
+        gv = jax.lax.dot_general(
+            g, v, (((1,), (1,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32,
+        )
+        cot_ref[1 - par, dst] += gv * s_ref[0] if quant else gv
+
+    @pl.when(t < n_last)
+    def _from_dy():
+        # the last factor's cotangent is dy itself: its block `src` is the
+        # one the dy BlockSpec fetched for this step
+        scatter(dy_ref[...].astype(jnp.float32))
+
+    @pl.when(t >= n_last)
+    def _from_scratch():
+        scatter(cot_ref[par, src])
 
     @pl.when(t == n_steps - 1)
     def _to_out():
@@ -278,6 +335,18 @@ def _dgrad_kernel(
             o_ref[:, b * blk : (b + 1) * blk] = cot_ref[out_par, b].astype(
                 o_ref.dtype
             )
+
+
+def _dy_index(first: int, n_last: int, cols: int):
+    """dy BlockSpec index map: during the ``n_last`` walk steps of the last
+    factor (from step ``first``) fetch the cotangent block the step reads;
+    park on block 0 (the last one that phase fetched) otherwise."""
+
+    def index(bi, t, meta):
+        live = (t >= first) & (t < first + n_last)
+        return (bi, jnp.where(live, meta[t * cols + 1], 0))
+
+    return index
 
 
 def chain_dgrad(
@@ -300,37 +369,38 @@ def chain_dgrad(
     """
     b, out_w = dy.shape
     blk = plan.block
-    rev = plan.reverse()  # the transposed chain this kernel walks
     n_steps = plan.n_steps
+    n_last = n_steps - plan.offsets[-2]  # walk steps of the last factor
     assert b % bt == 0, (b, bt)
-    bt = fit_bt(plan, bt, jnp.dtype(dy.dtype).itemsize, wgrad=False)
-    assert out_w == rev.in_blocks[0] * blk, (out_w, rev.in_blocks[0], blk)
+    assert out_w == plan.out_blocks[-1] * blk, (out_w, plan.out_blocks[-1], blk)
     assert values.shape == (n_steps, blk, blk), values.shape
     quant = scales is not None
+    check_bwd_bt(plan, bt, jnp.dtype(dy.dtype).itemsize, wgrad=False, quant=quant)
     meta = dgrad_meta(plan, in_idx)
-    in_pad = rev.out_blocks[-1] * blk
+    in_pad = plan.in_blocks[0] * blk
     grid = (b // bt, n_steps)
 
     in_specs = [
-        pl.BlockSpec((bt, out_w), lambda bi, t, meta: (bi, 0)),
+        pl.BlockSpec((bt, blk), _dy_index(0, n_last, DGRAD_META_COLS)),
         # the t-th reversed flat block — streams with double buffering
         pl.BlockSpec((1, blk, blk), lambda bi, t, meta: (n_steps - 1 - t, 0, 0)),
     ]
-    operands = [meta, dy, values]
+    operands = [meta.reshape(-1), dy, values]
     if quant:
         assert scales.shape == (n_steps, blk), scales.shape
-        in_specs.append(pl.BlockSpec((1, blk), lambda bi, t, meta: (n_steps - 1 - t, 0)))
-        operands.append(scales)
+        in_specs.append(pl.BlockSpec((1, 1, blk), lambda bi, t, meta: (n_steps - 1 - t, 0, 0)))
+        operands.append(scales.reshape(n_steps, 1, blk))
 
     return pl.pallas_call(
         functools.partial(
             _dgrad_kernel,
-            n_out_last=rev.in_blocks[0],
-            n_in0=rev.out_blocks[-1],
+            n_last=n_last,
+            n_in0=plan.in_blocks[0],
             blk=blk,
             n_steps=n_steps,
             out_par=plan.n_factors % 2,
             quant=quant,
+            precision=dot_precision(dy.dtype),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -339,10 +409,11 @@ def chain_dgrad(
             out_specs=pl.BlockSpec((bt, in_pad), lambda bi, t, meta: (bi, 0)),
             scratch_shapes=[
                 # cotangent ping-pong, f32 (scatter-accumulated in place)
-                pltpu.VMEM((2, rev.max_blocks, bt, blk), jnp.float32),
+                pltpu.VMEM((2, plan.act_blocks, bt, blk), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, in_pad), dy.dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
 
@@ -353,21 +424,22 @@ def chain_dgrad(
 
 
 def _wgrad_kernel(
-    meta_ref, x_ref, dy_ref, v_ref, *refs, s_pre,
-    n_in0, n_out_last, blk, quant,
+    meta_ref, x_ref, dy_ref, v_ref, *refs, s_pre, n_last, n_in0, blk, quant,
+    precision,
 ):
-    # Quantized chains dequantize the streamed block in VMEM once per step;
-    # the same dequantized block feeds the recompute dot (fwd phase) and the
-    # cotangent propagation (walk phase), so the checkpoint-free recompute
-    # stays a single value stream and the backward stays ≤ 2 launches.
+    # Quantized chains stream the step's (1, blk) f32 scale row next to the
+    # code block and apply it on the activation side — (a·diag(s)) @ Q in
+    # the recompute, (g @ Qᵀ)·diag(s) in the propagation — so one value
+    # stream feeds both phases and the backward stays ≤ 2 launches.
     if quant:
         s_ref, o_ref, acts_ref, cot_ref, acc_ref = refs
     else:
         o_ref, acts_ref, cot_ref, acc_ref = refs
     t = pl.program_id(1)
+    row = t * WGRAD_META_COLS
     v = v_ref[0]
     if quant:
-        v = v.astype(jnp.float32) * s_ref[0][:, None]
+        v = v.astype(jnp.float32)
 
     @pl.when(t == 0)
     def _load_x():
@@ -378,56 +450,65 @@ def _wgrad_kernel(
     def _recompute():
         # Forward step (factors 0..J-2), identical framing to the forward
         # kernel; flushes land in the flat per-factor activation scratch.
-        @pl.when(meta_ref[t, 2] == 1)
+        @pl.when(meta_ref[row + 2] == 1)
         def _open():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
+        a = acts_ref[meta_ref[row + 5] + meta_ref[row]]
         acc_ref[...] += jnp.dot(
-            acts_ref[meta_ref[t, 5] + meta_ref[t, 0]],
+            a * s_ref[0] if quant else a,
             v,
+            precision=precision,
             preferred_element_type=jnp.float32,
         )
 
-        @pl.when(meta_ref[t, 3] == 1)
+        @pl.when(meta_ref[row + 3] == 1)
         def _flush():
             cols = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
-            acts_ref[meta_ref[t, 6] + meta_ref[t, 1]] = jnp.where(
-                cols < meta_ref[t, 4], acc_ref[...], 0.0
+            acts_ref[meta_ref[row + 6] + meta_ref[row + 1]] = jnp.where(
+                cols < meta_ref[row + 4], acc_ref[...], 0.0
             )
 
-    @pl.when(t == s_pre)
-    def _load_dy():
-        for b in range(n_out_last):
-            cot_ref[0, b] = dy_ref[:, b * blk : (b + 1) * blk].astype(jnp.float32)
+    dst = meta_ref[row]
+    src = meta_ref[row + 1]
+    par = meta_ref[row + 2]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (x_ref.shape[0], blk), 1)
 
-    @pl.when(t >= s_pre)
-    def _walk():
-        dst = meta_ref[t, 0]
-        src = meta_ref[t, 1]
-        par = meta_ref[t, 2]
-
-        @pl.when(meta_ref[t, 3] == 1)
-        def _open_factor():
-            cot_ref[1 - par] = jnp.zeros(cot_ref.shape[1:], cot_ref.dtype)
-
-        cols = jax.lax.broadcasted_iota(jnp.int32, cot_ref.shape[2:], 1)
-        g = jnp.where(cols < meta_ref[t, 4], cot_ref[par, src], 0.0)
+    def walk(g):
+        g = jnp.where(cols < meta_ref[row + 4], g, 0.0)
         # per-slot cotangent block: a_jᵀ @ g  (blk × blk), written once
         o_ref[0, 0] = jax.lax.dot_general(
-            acts_ref[meta_ref[t, 5] + dst],
+            acts_ref[meta_ref[row + 5] + dst],
             g,
             (((0,), (0,)), ((), ())),
+            precision=precision,
             preferred_element_type=jnp.float32,
         )
 
-        @pl.when(meta_ref[t, 6] == 1)
+        @pl.when(meta_ref[row + 6] == 1)
         def _propagate():
-            cot_ref[1 - par, dst] += jax.lax.dot_general(
+            gv = jax.lax.dot_general(
                 g,
                 v,
                 (((1,), (1,)), ((), ())),
+                precision=precision,
                 preferred_element_type=jnp.float32,
             )
+            cot_ref[1 - par, dst] += gv * s_ref[0] if quant else gv
+
+    @pl.when((t >= s_pre) & (meta_ref[row + 3] == 1))
+    def _open_factor():
+        cot_ref[1 - par] = jnp.zeros(cot_ref.shape[1:], cot_ref.dtype)
+
+    @pl.when((t >= s_pre) & (t < s_pre + n_last))
+    def _walk_dy():
+        # the last factor's cotangent is dy itself (block `src`, fetched
+        # by the dy BlockSpec for this step)
+        walk(dy_ref[...].astype(jnp.float32))
+
+    @pl.when(t >= s_pre + n_last)
+    def _walk_scratch():
+        walk(cot_ref[par, src])
 
 
 def chain_wgrad(
@@ -455,21 +536,18 @@ def chain_wgrad(
     blk = plan.block
     n_steps = plan.n_steps
     s_pre = plan.offsets[plan.n_factors - 1]
+    n_last = n_steps - s_pre  # walk steps of the last factor
     assert b % bt == 0, (b, bt)
-    bt = fit_bt(plan, bt, jnp.dtype(x.dtype).itemsize, wgrad=True)
     assert dy.shape == (b, plan.out_blocks[-1] * blk), dy.shape
     assert values.shape == (n_steps, blk, blk), values.shape
     quant = scales is not None
+    check_bwd_bt(plan, bt, jnp.dtype(x.dtype).itemsize, wgrad=True, quant=quant)
     meta = wgrad_meta(plan, in_idx)
     n_tiles = b // bt
-    out_w = plan.out_blocks[-1] * blk
     grid = (n_tiles, s_pre + n_steps)
 
     def _v_index(bi, t, meta):
         return (jnp.where(t < s_pre, t, s_pre + n_steps - 1 - t), 0, 0)
-
-    def _s_index(bi, t, meta):
-        return (jnp.where(t < s_pre, t, s_pre + n_steps - 1 - t), 0)
 
     def _o_index(bi, t, meta):
         # forward-phase steps park on the first walk block (S-1) so no
@@ -479,23 +557,24 @@ def chain_wgrad(
 
     in_specs = [
         pl.BlockSpec((bt, in_w), lambda bi, t, meta: (bi, 0)),
-        pl.BlockSpec((bt, out_w), lambda bi, t, meta: (bi, 0)),
+        pl.BlockSpec((bt, blk), _dy_index(s_pre, n_last, WGRAD_META_COLS)),
         pl.BlockSpec((1, blk, blk), _v_index),
     ]
-    operands = [meta, x, dy, values]
+    operands = [meta.reshape(-1), x, dy, values]
     if quant:
         assert scales.shape == (n_steps, blk), scales.shape
-        in_specs.append(pl.BlockSpec((1, blk), _s_index))
-        operands.append(scales)
+        in_specs.append(pl.BlockSpec((1, 1, blk), _v_index))
+        operands.append(scales.reshape(n_steps, 1, blk))
 
     partials = pl.pallas_call(
         functools.partial(
             _wgrad_kernel,
             s_pre=s_pre,
+            n_last=n_last,
             n_in0=plan.in_blocks[0],
-            n_out_last=plan.out_blocks[-1],
             blk=blk,
             quant=quant,
+            precision=dot_precision(x.dtype),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -506,12 +585,13 @@ def chain_wgrad(
                 # every factor's input activation, flat (recompute target)
                 pltpu.VMEM((sum(plan.in_blocks), bt, blk), jnp.float32),
                 # cotangent ping-pong for the walk
-                pltpu.VMEM((2, plan.max_blocks, bt, blk), jnp.float32),
+                pltpu.VMEM((2, plan.act_blocks, bt, blk), jnp.float32),
                 # forward-phase f32 accumulator
                 pltpu.VMEM((bt, blk), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((n_tiles, n_steps, blk, blk), jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
     return partials[0] if n_tiles == 1 else partials.sum(axis=0)

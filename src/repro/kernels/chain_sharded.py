@@ -21,8 +21,8 @@ shards over ``'data'``.  This module plans and executes that layout:
 Feasibility is decided host-side by :func:`plan_shard` from static
 metadata only (block counts, concrete ``in_idx`` when available).  When
 the out-block counts don't divide ``n_model`` — or a ragged (non-block-
-multiple) feature dim would make the per-shard step tables diverge — the
-plan falls back to **replicated** weights with the batch sharded over
+multiple) *inner* feature dim would make the per-shard step tables
+diverge — the plan falls back to **replicated** weights with the batch sharded over
 every fitting mesh axis, reusing the divisibility-driven replication
 semantics of ``repro.distributed.sharding._fit_axes``: sharding degrades,
 it never errors.
@@ -49,7 +49,6 @@ import weakref
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.compress import BlockFaust, ChainPlan, pack_chain
@@ -172,9 +171,12 @@ def _model_blockers(bf: BlockFaust, n_model: int) -> str | None:
                 f"factor {j}: {f.n_out_blocks} out-blocks do not divide "
                 f"{n_model} model shards"
             )
-        if f.out_features != f.n_out_blocks * f.bn:
+        if f.out_features != f.n_out_blocks * f.bn and j < len(bf.factors) - 1:
+            # a ragged *inner* width must be masked on one shard only, so
+            # per-shard step tables would diverge; the last factor's tail
+            # is sliced off after the apply and needs no mask
             return (
-                f"factor {j}: ragged out width {f.out_features} "
+                f"factor {j}: ragged inner width {f.out_features} "
                 f"(per-shard step tables would diverge)"
             )
     for j, (a, b) in enumerate(zip(bf.factors[:-1], bf.factors[1:])):
@@ -476,12 +478,12 @@ def _apply_model_sharded(x2, bf, mesh, plan, use_kernel, bt, interpret, fac_scal
         # scale rows shard by out-block exactly like the blocks they scale
         in_specs += [P(model_axis, None, None)] * n_fac
         operands += list(fac_scales)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=P(plan.data_spec, model_axis),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x2, *operands)
 
@@ -502,12 +504,12 @@ def _apply_replicated(x2, bf, mesh, plan, use_kernel, bt, interpret, scales=None
         if scales is not None:  # replicated scale rows next to replicated codes
             in_specs.append(P(None, None))
             operands.append(scales)
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=P(plan.data_spec, None),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(x2, *operands)
 
@@ -553,12 +555,12 @@ def _apply_replicated(x2, bf, mesh, plan, use_kernel, bt, interpret, scales=None
     for f in bf.factors:
         flat += [f.values, f.in_idx]
         specs += [P(None, None, None, None), P(None, None)]
-    fn = shard_map(
+    fn = jax.shard_map(
         local_ref,
         mesh=mesh,
         in_specs=tuple(specs),
         out_specs=P(plan.data_spec, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x2, *flat)
 

@@ -17,7 +17,8 @@ Notes recorded alongside the numbers:
   * conditionals (gemma3's local/global branches never appear — patterns
     are static) — conditionals if present are counted max-branch.
 
-Peak constants: builtin TPU-v5e numbers by default, replaced by
+Peak constants: builtin per-chip numbers keyed by ``device_kind`` (an
+unknown TPU kind raises; off-TPU the reference v5e is priced), replaced by
 *measured* values when ``scripts/calibrate_roofline.py`` has cached a
 ``roofline.json`` for this host (``~/.cache/repro/roofline.json``;
 ``REPRO_ROOFLINE`` overrides the path, ``REPRO_ROOFLINE=builtin`` forces
@@ -27,9 +28,9 @@ the configured path or its mtime changes — so a calibration written
 mid-process, or a ``REPRO_ROOFLINE`` flip after first import, takes
 effect on the next decision instead of being silently ignored.
 :func:`reload` forces a re-read.  The module-level ``PEAK_FLOPS`` /
-``HBM_BW`` / ``LINK_BW`` / ``T_LAUNCH_US`` / :data:`ROOFLINE_SOURCE`
-are import-time snapshots kept for static consumers (``launch/report``);
-anything that must see post-import calibrations uses the accessor.
+``HBM_BW`` / ``LINK_BW`` are the reference chip's builtin numbers, kept
+for static consumers (``launch/report``); anything that prices a live
+decision uses the accessor.
 """
 from __future__ import annotations
 
@@ -41,12 +42,39 @@ from collections import defaultdict
 
 import numpy as np
 
-_BUILTIN = {
-    "peak_flops": 197e12,  # bf16 / chip (TPU v5e)
-    "hbm_bw": 819e9,  # bytes/s / chip
-    "link_bw": 50e9,  # bytes/s / link (ICI)
-    "t_launch_us": 2.0,  # fixed per-launch overhead (µs)
+# Builtin per-chip constants, keyed by ``jax.Device.device_kind``.  Peaks
+# from the Google Cloud TPU documentation ("TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM); ``link_bw`` and ``t_launch_us`` are model constants that
+# no chip run has measured yet.
+_BUILTIN_BY_KIND = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,  # bf16 / chip
+        "hbm_bw": 819e9,  # bytes/s / chip
+        "link_bw": 50e9,  # bytes/s / link (ICI)
+        "t_launch_us": 2.0,  # fixed per-launch overhead (µs)
+    },
 }
+# Off-TPU (CPU tests, compile rehearsals) dispatch prices the chip the
+# repo deploys on, so its decisions stay a pure function of the shapes.
+_REFERENCE_KIND = "TPU v5 lite"
+_BUILTIN = _BUILTIN_BY_KIND[_REFERENCE_KIND]
+
+
+def builtin_constants() -> dict:
+    """Builtin constants for the default device: its ``device_kind`` entry
+    on a TPU — a TPU kind missing from the table raises rather than being
+    priced as another chip — and the reference chip's off-TPU."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return dict(_BUILTIN)
+    if dev.device_kind not in _BUILTIN_BY_KIND:
+        raise KeyError(
+            f"no builtin roofline constants for TPU kind {dev.device_kind!r}; "
+            f"known kinds: {sorted(_BUILTIN_BY_KIND)}"
+        )
+    return dict(_BUILTIN_BY_KIND[dev.device_kind])
 
 
 def roofline_cache_path() -> str:
@@ -59,27 +87,28 @@ def roofline_cache_path() -> str:
 
 def load_roofline() -> tuple[dict, str]:
     """(constants dict, source) — measured values from the calibration
-    cache when present and sane, builtin TPU-v5e numbers otherwise.
-    Unknown/invalid keys fall back individually, so a partial cache still
-    contributes what it measured."""
+    cache when present and sane, the device's builtin constants otherwise
+    (:func:`builtin_constants`).  Unknown/invalid keys fall back
+    individually, so a partial cache still contributes what it measured."""
+    builtin = builtin_constants()
     path = roofline_cache_path()
     if path.lower() in ("", "0", "builtin", "off"):
-        return dict(_BUILTIN), "builtin"
+        return builtin, "builtin"
     try:
         with open(path, encoding="utf-8") as f:
             data = json.load(f)
         if not isinstance(data, dict):
-            return dict(_BUILTIN), "builtin"
+            return builtin, "builtin"
         measured = {
             k: float(data[k])
-            for k in _BUILTIN
+            for k in builtin
             if isinstance(data.get(k), (int, float)) and float(data[k]) > 0
         }
         if not measured:
-            return dict(_BUILTIN), "builtin"
-        return {**_BUILTIN, **measured}, f"measured:{path}"
+            return builtin, "builtin"
+        return {**builtin, **measured}, f"measured:{path}"
     except (OSError, ValueError):
-        return dict(_BUILTIN), "builtin"
+        return builtin, "builtin"
 
 
 # Live-state cache for :func:`roofline_constants`: (path, mtime_ns) of the
@@ -118,11 +147,11 @@ def reload() -> tuple[dict, str]:
     return roofline_constants()
 
 
-_VALUES, ROOFLINE_SOURCE = load_roofline()
-PEAK_FLOPS = _VALUES["peak_flops"]
-HBM_BW = _VALUES["hbm_bw"]
-LINK_BW = _VALUES["link_bw"]
-T_LAUNCH_US = _VALUES["t_launch_us"]
+# Reference-chip snapshots for static consumers (``launch/report``'s
+# dry-run report, which models a v5e mesh); live pricing uses the accessor.
+PEAK_FLOPS = _BUILTIN["peak_flops"]
+HBM_BW = _BUILTIN["hbm_bw"]
+LINK_BW = _BUILTIN["link_bw"]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

@@ -1,21 +1,25 @@
-"""Serving launcher: batched prefill + greedy decode.
+"""Serving launcher: continuous-batching engine, greedy decode.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch gemma_2b --smoke \
       --batch 4 --prompt-len 64 --new-tokens 32
+
+Exits non-zero when any request ends in a state other than ``done``
+(failed, timed out or rejected).
 """
 from __future__ import annotations
 
 import argparse
+from collections import Counter
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke
 from repro.data.pipeline import DataConfig, global_batch
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import lm
-from repro.runtime.server import Server
+from repro.runtime.engine import DONE, Engine, LMExecutor
 
 
 def main() -> None:
@@ -26,9 +30,10 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=32)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
-    params = lm.init_model(jax.random.PRNGKey(0), cfg)
+    params = jax.jit(lambda key: lm.init_model(key, cfg))(jax.random.PRNGKey(0))
 
     data_cfg = DataConfig(
         vocab=cfg.vocab,
@@ -38,15 +43,34 @@ def main() -> None:
         n_vision_tokens=cfg.n_vision_tokens,
         d_model=cfg.d_model,
     )
-    batch = {k: jnp.asarray(v) for k, v in global_batch(data_cfg, 0).items()}
+    batch = global_batch(data_cfg, 0)
 
-    server = Server(cfg, params, max_len=args.prompt_len + args.new_tokens)
-    gen, stats = server.generate(batch, args.new_tokens)
-    print(f"generated shape: {gen.shape}")
-    print(
-        f"prefill {stats.prefill_s*1e3:.1f} ms; decode {stats.decode_s*1e3:.1f} ms "
-        f"({stats.tokens_per_s:.1f} tok/s)"
+    ex = LMExecutor(
+        cfg, params, max_len=args.prompt_len + args.new_tokens, n_slots=args.batch
     )
+    engine = Engine(ex)
+    rids = [
+        engine.submit(
+            batch["tokens"][i],
+            args.new_tokens,
+            extras={k: v[i] for k, v in batch.items() if k != "tokens"},
+        )
+        for i in range(args.batch)
+    ]
+    engine.run()
+    states = Counter(engine.status(r) for r in rids)
+    st = engine.stats
+    print(f"requests: {dict(states)}")
+    print(
+        f"prefill {st.prefill_s*1e3:.1f} ms; decode {st.decode_s*1e3:.1f} ms "
+        f"({st.tokens_per_s:.1f} tok/s); retries {st.retries}"
+    )
+    if st.faust_dispatch is not None:
+        print(f"faust dispatch: {st.faust_dispatch.backend} bt={st.faust_dispatch.bt}")
+    if states[DONE] != len(rids):
+        raise SystemExit(f"{len(rids) - states[DONE]} request(s) did not finish: {dict(states)}")
+    gen = np.stack([engine.result(r) for r in rids])
+    print(f"generated shape: {gen.shape}")
 
 
 if __name__ == "__main__":

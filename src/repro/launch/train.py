@@ -19,6 +19,7 @@ import jax
 
 from repro.configs import get_config, get_smoke
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.layers.faust_linear import FaustSpec
 from repro.optim.adamw import AdamWConfig
 from repro.optim.compression import TopKConfig
@@ -48,6 +49,7 @@ def main() -> None:
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--mesh", action="store_true", help="use production mesh")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if args.faust:

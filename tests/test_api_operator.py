@@ -522,3 +522,93 @@ def test_pack_cache_not_poisoned_across_jits():
     # eager apply afterwards still works (and may now cache concretely)
     y3 = op.apply(x, backend="fused", use_kernel=False)
     np.testing.assert_allclose(np.asarray(y3), np.asarray(y1), rtol=1e-6)
+
+
+def _small_block_chain(seed=60):
+    from repro.core.compress import random_block_factor
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return BlockFaust(
+        (random_block_factor(keys[0], 32, 32, 8, 8, 2),
+         random_block_factor(keys[1], 32, 40, 8, 8, 2)),
+        jnp.asarray(1.1),
+    )
+
+
+@pytest.mark.parametrize("staged", [True, False], ids=["jit", "eager"])
+def test_auto_fused_choice_stays_fused(monkeypatch, staged):
+    """Regression: an exception inside the fused path (a JAX API that no
+    longer exists) was swallowed by degraded-mode dispatch, which served
+    the per-factor path with source="demoted".  With degraded mode on, a
+    packable chain that auto-dispatches to fused must run fused."""
+    from repro.api import last_report
+
+    monkeypatch.setenv("REPRO_DEGRADED", "1")
+    op = FaustOp.wrap(_small_block_chain())
+    x = jax.random.normal(jax.random.PRNGKey(61), (4, 32))
+    assert op.dispatch_for(4, x.dtype).backend == "fused"
+
+    def apply(v):
+        return op.apply(v, backend="auto", use_kernel=True)
+
+    y = jax.jit(apply)(x) if staged else apply(x)
+    rep = last_report()
+    assert rep.backend == "fused" and rep.source != "demoted", rep.reason
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(x @ op.todense()), rtol=1e-5, atol=1e-5
+    )
+
+
+def test_dispatch_fits_one_tile_that_the_kernels_run(monkeypatch):
+    """Dispatch halves the requested tile until the kernels fit, records
+    it on the report, and the apply runs at exactly that tile; with
+    ``grad`` the backward's footprint narrows it further."""
+    from repro.kernels import chain as kchain
+    from repro.kernels import chain_bwd as kbwd
+
+    op = FaustOp.wrap(_small_block_chain())
+    x = jax.random.normal(jax.random.PRNGKey(63), (16, 32))
+    plan = op.chain_plan()
+    monkeypatch.setattr(
+        kchain, "VMEM_BUDGET_BYTES", kchain.fwd_vmem_bytes(plan, 16, 4, 4, False)
+    )
+    assert op.dispatch_for(16, x.dtype, bt=32).bt == 16
+    y = op.apply(x, backend="fused", use_kernel=True, interpret=True, bt=32)
+    assert last_report().bt == 16
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(x @ op.todense()), rtol=1e-5, atol=1e-5
+    )
+    # a tile that does not fit is refused by the kernel, never refitted
+    with pytest.raises(ValueError, match="VMEM"):
+        kchain.check_fwd_bt(plan, 32, 4, 4, False)
+    monkeypatch.setattr(
+        kbwd, "_VMEM_BUDGET_BYTES", kbwd.bwd_vmem_bytes(plan, 8, 4, wgrad=True)
+    )
+    assert op.dispatch_for(16, x.dtype, bt=32, grad=True).bt == 8
+    assert op.dispatch_for(16, x.dtype, bt=32).bt == 16
+
+
+def test_dispatch_rules_out_fused_chain_that_cannot_fit(monkeypatch):
+    """A chain whose fused kernels cannot fit VMEM at any batch tile is
+    priced without ``fused`` (the reason says why) and a forced fused
+    apply fails from its shapes, before anything compiles."""
+    from repro.kernels import chain as kchain
+    from repro.kernels import chain_bwd as kbwd
+
+    op = FaustOp.wrap(_small_block_chain())
+    x = jax.random.normal(jax.random.PRNGKey(62), (4, 32))
+    plan = op.chain_plan()
+    fwd_need = kchain.fwd_vmem_bytes(plan, kchain.MIN_BT, 4, 4, False)
+    monkeypatch.setattr(kchain, "VMEM_BUDGET_BYTES", fwd_need - 1)
+    rep = op.dispatch_for(4, x.dtype)
+    assert "fused" not in rep.feasible
+    assert "fused ruled out" in rep.reason
+    with pytest.raises(ValueError, match="VMEM"):
+        op.apply(x, backend="fused", use_kernel=True)
+    # the backward has its own budget: a forward that fits but a backward
+    # that does not rules fused out for training applies only
+    monkeypatch.setattr(kchain, "VMEM_BUDGET_BYTES", fwd_need)
+    assert op.dispatch_for(4, x.dtype).backend == "fused"
+    monkeypatch.setattr(kbwd, "_VMEM_BUDGET_BYTES", 1024)
+    assert "fused" not in op.dispatch_for(4, x.dtype, grad=True).feasible
+    assert op.dispatch_for(4, x.dtype).backend == "fused"

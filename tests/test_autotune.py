@@ -323,6 +323,23 @@ def test_roofline_reload_hook(tmp_path, monkeypatch):
     assert consts["peak_flops"] == roofline._BUILTIN["peak_flops"]
 
 
+def test_builtin_roofline_keyed_by_device_kind(monkeypatch):
+    """Builtin constants come from the device's own ``device_kind``: a TPU
+    kind missing from the table raises instead of being priced as a v5e;
+    off-TPU the reference chip prices the decision."""
+    class Dev:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [Dev()])
+    assert roofline.builtin_constants()["peak_flops"] == 197e12
+    Dev.device_kind = "TPU v99"
+    with pytest.raises(KeyError, match="TPU v99"):
+        roofline.builtin_constants()
+    Dev.platform, Dev.device_kind = "cpu", "cpu"
+    assert roofline.builtin_constants() == roofline._BUILTIN
+
+
 # ---------------------------------------------------------------------------
 # satellite: wgrad spill priced at the real batch tile
 # ---------------------------------------------------------------------------

@@ -424,6 +424,7 @@ def test_degraded_dispatch_demotes_once_and_quarantines(monkeypatch):
     from repro.api import autotune, dispatch
 
     jax.config.update("jax_platform_name", "cpu")
+    monkeypatch.setenv("REPRO_DEGRADED", "1")  # degraded mode is opt-in
     op, _ = _packed_op(seed=20)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, op.shape[0]))
     ref = np.asarray(op.apply(x, backend="bsr"))
@@ -454,7 +455,8 @@ def test_degraded_dispatch_demotes_once_and_quarantines(monkeypatch):
 
 
 def test_degraded_dispatch_respects_forced_and_env(monkeypatch):
-    """Forced backends stay loud; REPRO_DEGRADED=off makes auto loud."""
+    """Forced backends stay loud; auto stays loud unless REPRO_DEGRADED
+    opts in."""
     import jax
 
     import repro.kernels.ops as kops
@@ -466,8 +468,12 @@ def test_degraded_dispatch_respects_forced_and_env(monkeypatch):
         raise RuntimeError("pallas launch failed")
 
     monkeypatch.setattr(kops, "packed_chain_apply", boom)
+    monkeypatch.setenv("REPRO_DEGRADED", "1")
     with pytest.raises(RuntimeError, match="pallas launch failed"):
         op.apply(x, backend="fused")
+    monkeypatch.delenv("REPRO_DEGRADED")
+    with pytest.raises(RuntimeError, match="pallas launch failed"):
+        op.apply(x)
     monkeypatch.setenv("REPRO_DEGRADED", "off")
     with pytest.raises(RuntimeError, match="pallas launch failed"):
         op.apply(x)

@@ -170,6 +170,30 @@ def test_sharded_fallback_ragged_chain():
 
 
 @needs_mesh
+def test_sharded_ragged_last_factor_model_mode():
+    """A ragged output width (vocabulary tail) keeps the model-sharded
+    plan; forward and grad match the single-device fused apply."""
+    keys = jax.random.split(jax.random.PRNGKey(16), 2)
+    bf = BlockFaust(
+        (random_block_factor(keys[0], 32, 32, 8, 8, 2),
+         random_block_factor(keys[1], 32, 29, 8, 8, 2)),
+        jnp.asarray(0.9, jnp.float32),
+    )
+    mesh = make_debug_mesh(1, 4)
+    assert cs.plan_shard(bf, mesh).mode == "model"
+    placed = cs.place_blockfaust(bf, mesh)
+    op = FaustOp.wrap(placed).with_sharding(ShardSpec(mesh))
+    x = jax.random.normal(jax.random.PRNGKey(17), (5, 32))
+    want = FaustOp.wrap(bf).apply(x, backend="fused", use_kernel=False)
+    got = op.apply(x, backend="fused_sharded", use_kernel=False)
+    assert got.shape == (5, 29)
+    assert _rel(got, want) <= PARITY
+    g_sh = jax.grad(lambda v: op.apply(v, backend="fused_sharded", use_kernel=False).sum())(x)
+    g_one = jax.grad(lambda v: FaustOp.wrap(bf).apply(v, backend="fused", use_kernel=False).sum())(x)
+    assert _rel(g_sh, g_one) <= PARITY
+
+
+@needs_mesh
 def test_sharded_apply_jit_and_grad():
     bf = _chain()
     mesh = make_debug_mesh(2, 2)

@@ -23,7 +23,7 @@ from repro.kernels import chain_sharded as cs
 
 jax.config.update("jax_platform_name", "cpu")
 
-MESH = AbstractMesh((("data", 2), ("model", 4)))
+MESH = AbstractMesh((2, 4), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------
@@ -179,8 +179,17 @@ def test_plan_ragged_falls_back_replicated():
     assert "ragged" in plan.reason
 
 
+def test_plan_ragged_last_factor_stays_model_sharded():
+    # a vocabulary-style ragged *output* width is sliced off after the
+    # apply, so the per-shard step tables stay identical
+    bf = _chain(nblocks=(4, 4, 4), feats=(32, 32, 30))
+    plan = cs.plan_shard(bf, MESH)
+    assert plan.mode == "model", plan.reason
+    assert plan.segments[-1].plan.out_feats[-1] == 8  # one full local block
+
+
 def test_plan_no_model_axis_falls_back():
-    mesh = AbstractMesh((("data", 2),))
+    mesh = AbstractMesh((2,), ("data",))
     plan = cs.plan_shard(_chain(), mesh)
     assert plan.mode == "replicated"
     assert plan.n_model == 1 and plan.n_batch_shards == 2

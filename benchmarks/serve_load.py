@@ -87,10 +87,9 @@ def _occ_str(stats) -> str:
 
 
 def _last_dispatch(stats):
-    for rep in reversed(stats.dispatch_per_step):
-        if rep is not None:
-            return rep
-    return None
+    """The dispatch report at the widest live batch served."""
+    reps = [rep for _, rep in sorted(stats.dispatch_by_batch.items()) if rep is not None]
+    return reps[-1] if reps else None
 
 
 def _saturated(cfg, params) -> tuple:

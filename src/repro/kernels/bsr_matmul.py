@@ -98,4 +98,5 @@ def bsr_matmul(
         ),
         out_shape=jax.ShapeDtypeStruct((b, o * bn), x.dtype),
         interpret=interpret,
+        name="faust_bsr_matmul",
     )(in_idx.reshape(-1), x, values)

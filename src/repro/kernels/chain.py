@@ -302,4 +302,5 @@ def chain_matmul(
         out_shape=jax.ShapeDtypeStruct((b, out_w), x.dtype),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="faust_chain_fwd",
     )(*operands)

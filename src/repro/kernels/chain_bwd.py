@@ -415,6 +415,7 @@ def chain_dgrad(
         out_shape=jax.ShapeDtypeStruct((b, in_pad), dy.dtype),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="faust_chain_dgrad",
     )(*operands)
 
 
@@ -593,6 +594,7 @@ def chain_wgrad(
         out_shape=jax.ShapeDtypeStruct((n_tiles, n_steps, blk, blk), jnp.float32),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="faust_chain_wgrad",
     )(*operands)
     return partials[0] if n_tiles == 1 else partials.sum(axis=0)
 

@@ -20,9 +20,10 @@ module replaces it with a proper engine:
   batch.  Requests that hit their budget complete and free their slot
   immediately — the batch *breathes*, which is exactly the small-batch
   regime where the fused chain kernel wins (BENCH ``apply_*`` rows).
-* :class:`EngineStats` — queue depth and batch-occupancy per step,
-  admitted/completed/evicted counts, per-request TTFT/TPOT, and the
-  per-step FAµST dispatch decision.
+* :class:`EngineStats` — queue depth and batch-occupancy over the
+  decode steps, admitted/completed/evicted counts, per-request TTFT/TPOT,
+  and the FAµST dispatch decision at each live batch size.  Nothing in
+  it grows with the number of steps served.
 
 **Static shapes.** ``lm.prefill`` / ``lm.decode_step`` never see a
 dynamic shape: the cache pool keeps the slot dim at ``n_slots``; a decode
@@ -37,9 +38,11 @@ distinct live batch size / prompt length, not per slot or schedule.
 **Live-batch dispatch.** Each decode step consults the dispatch layer at
 the *live* batch size (:meth:`repro.api.FaustOp.dispatch_for`,
 ``record=False``) so the backend choice — and the autotuned ``bt`` tile —
-follows the batch as it breathes; the per-step
-:class:`~repro.api.dispatch.DispatchReport` (including its autotune
-``source``) is recorded on :class:`EngineStats`.
+follows the batch as it breathes; the
+:class:`~repro.api.dispatch.DispatchReport` of each live batch size
+(including its autotune ``source``) is recorded on :class:`EngineStats`.
+:class:`LMExecutor` answers the query once per batch size and keeps the
+answer until the next :meth:`LMExecutor.swap_unembed`.
 
 **Eviction.** ``Engine.evict(rid)`` preempts a live request: its slot is
 freed (and may be reused immediately), the request returns to the *front*
@@ -81,12 +84,41 @@ scheduler itself is testable with a pure-numpy deterministic model
 :class:`LMExecutor` is the real jax implementation;
 ``runtime/server.py``'s ``Server.generate`` is now a thin shim over
 ``Engine`` + ``LMExecutor``.
+
+**Tracing.** The engine and :class:`LMExecutor` mark their work with host
+spans (``jax.profiler.TraceAnnotation``), which land in the profiler's
+trace on the same clock as the device's op events, so every stretch in
+which the chip sits idle can be put down to what the host was doing.
+To record them, wrap serving in ``with jax.profiler.trace(log_dir):``
+and open the ``.xplane.pb`` it writes (TensorBoard, Perfetto, or
+``jax.profiler.ProfileData``).  When no trace is recording a span costs
+one check and builds nothing.  The spans, outermost first:
+
+* ``engine.step`` — one :meth:`Engine.step` tick (arg ``step``, a tick
+  counter); the device idles *between* these spans while the caller's
+  own code runs.
+* ``engine.admit`` — one admission: prefill, its sample and NaN guard
+  (args ``rid``, ``tokens``).
+* ``engine.decode`` — the decode half of the tick (arg ``rows``), with
+  ``engine.dispatch_query`` around the advisory dispatch lookup.
+* ``executor.prefill`` / ``executor.decode`` — the forward call, split
+  into ``executor.launch`` (argument conversion, host-to-device copies and
+  the jitted calls until they return, the ragged-tail replay included)
+  and ``executor.wait`` (blocking on the logits).
+* ``executor.sample`` / ``executor.row_finite`` — greedy argmax and the
+  NaN guard, each with its readback to the host.
+
+Time inside ``engine.step`` but outside every ``executor.*`` span is the
+engine's own bookkeeping (scheduling, the dispatch query, token append,
+completion).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import heapq
 import os
+import sys
 import time
 from collections import OrderedDict, deque
 from typing import Any, Callable, Protocol, Sequence
@@ -101,6 +133,20 @@ __all__ = [
     "LMExecutor",
     "Engine",
 ]
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _span(name: str, *args):
+    """Host span ``name`` on the profiler's clock (see "Tracing" above);
+    ``args`` alternate argument names and cheap scalar values.  While no
+    trace is recording — always, in a process that never loaded jax's
+    profiler — it returns a shared no-op context and builds nothing."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return _NO_SPAN
+    return prof.TraceAnnotation(name, **dict(zip(args[::2], args[1::2])))
 
 
 # ---------------------------------------------------------------------------
@@ -235,10 +281,11 @@ class EngineStats:
     quarantined: int = 0  # streams killed by the non-finite-logits guard
     demotions: int = 0  # degraded-mode dispatch fallbacks observed
     swap_rejects: int = 0  # guarded hot-swaps rolled back (streaming.swap)
-    # per-decode-step observability
-    queue_depth: list = dataclasses.field(default_factory=list)
+    # decode-step observability, constant in size however long it serves
+    queue_depth_max: int = 0  # deepest queue seen at a decode step
+    queue_depth_sum: int = 0  # queue depth summed over decode steps
     occupancy: dict = dataclasses.field(default_factory=dict)  # B_live -> steps
-    dispatch_per_step: list = dataclasses.field(default_factory=list)
+    dispatch_by_batch: dict = dataclasses.field(default_factory=dict)  # B_live -> last report
     # per-request latency (seconds, under the engine's clock)
     ttft_s: dict = dataclasses.field(default_factory=dict)
     tpot_s: dict = dataclasses.field(default_factory=dict)
@@ -251,11 +298,13 @@ class EngineStats:
         return self.tokens_decoded / self.decode_s if self.decode_s else 0.0
 
     def backend_counts(self) -> dict:
-        """Histogram of per-step dispatch decisions: backend -> steps."""
+        """Histogram of dispatch decisions over the decode steps:
+        backend -> steps (a live batch size's steps count under the
+        backend last reported for it)."""
         counts: dict[str, int] = {}
-        for rep in self.dispatch_per_step:
+        for b, rep in self.dispatch_by_batch.items():
             if rep is not None:
-                counts[rep.backend] = counts.get(rep.backend, 0) + 1
+                counts[rep.backend] = counts.get(rep.backend, 0) + self.occupancy.get(b, 0)
         return counts
 
 
@@ -317,7 +366,8 @@ class LMExecutor:
     buffer-wise.  The FAµST dispatch staged while tracing is captured
     (same mark technique as the old ``Server``) on ``faust_dispatch``;
     :meth:`dispatch_for` answers the engine's per-step advisory query
-    from the unembedding chain — the projection every decode step pays.
+    from the unembedding chain — the projection every decode step pays —
+    pricing each live batch size once.
     """
 
     def __init__(self, cfg, params, max_len: int, n_slots: int, mesh=None):
@@ -334,6 +384,7 @@ class LMExecutor:
         self.pool = lm.make_caches(cfg, n_slots, max_len, dtype=self._act_dtype)
         self.faust_dispatch = None  # last decision staged into a trace
         self._faust_op = self._build_faust_op()
+        self._dispatch_memo: dict = {}  # live batch -> advisory report
 
         dtype = self._act_dtype
 
@@ -388,7 +439,11 @@ class LMExecutor:
     def dispatch_for(self, batch: int):
         if self._faust_op is None:
             return None
-        return self._faust_op.dispatch_for(batch, self._act_dtype)
+        rep = self._dispatch_memo.get(batch)
+        if rep is None:
+            rep = self._faust_op.dispatch_for(batch, self._act_dtype)
+            self._dispatch_memo[batch] = rep
+        return rep
 
     def unembed_blockfaust(self):
         """The currently-published unembedding chain as a
@@ -434,56 +489,69 @@ class LMExecutor:
         unembed["faust"], _ = split_annotations(blockfaust_to_params(bf))
         self.params = {**self.params, "unembed": unembed}
         self._faust_op = self._build_faust_op()
+        self._dispatch_memo.clear()
 
     # -- Executor interface -------------------------------------------------
     def prefill_forward(self, slot: int, prompt: np.ndarray, extras: dict):
+        with _span("executor.prefill"):
+            return self._prefill_forward(slot, prompt, extras)
+
+    def _prefill_forward(self, slot, prompt, extras):
         from repro.api import dispatch as _dispatch
 
         jnp = self._jnp
-        prompt = np.asarray(prompt)
-        n = prompt.shape[-1]
-        chunk = self.cfg.attn_chunk
-        head, tail = prompt, prompt[..., :0]
-        if n > chunk and n % chunk:
-            # Chunked prefill (flash attention / SSD scan) requires
-            # S % attn_chunk == 0 for S > chunk.  Re-prefills of
-            # prompt+generated — the retry and evict re-admission paths —
-            # arrive at ragged lengths, so prefill the aligned prefix and
-            # replay the remainder through the decode step: the final
-            # replayed token's logits are exactly the full prompt's
-            # prefill logits (token-exact by construction).
-            aligned = (n // chunk) * chunk
-            head, tail = prompt[..., :aligned], prompt[..., aligned:]
-        batch = {"tokens": jnp.asarray(head)[None]}
-        for k, v in extras.items():
-            batch[k] = jnp.asarray(v)[None]
-        mark = _dispatch.last_report()
-        logits, self.pool = self._prefill_fn(
-            self.params, batch, self.pool, jnp.asarray(slot, jnp.int32)
-        )
-        slot_idx = jnp.asarray([slot], jnp.int32)
-        for i in range(tail.shape[-1]):
-            tok = jnp.asarray(tail[..., i : i + 1][None])  # (1,1)/(1,K,1)
-            logits, self.pool = self._decode_fn(
-                self.params, tok, self.pool, slot_idx
+        with _span("executor.launch"):
+            prompt = np.asarray(prompt)
+            n = prompt.shape[-1]
+            chunk = self.cfg.attn_chunk
+            head, tail = prompt, prompt[..., :0]
+            if n > chunk and n % chunk:
+                # Chunked prefill (flash attention / SSD scan) requires
+                # S % attn_chunk == 0 for S > chunk.  Re-prefills of
+                # prompt+generated — the retry and evict re-admission paths —
+                # arrive at ragged lengths, so prefill the aligned prefix and
+                # replay the remainder through the decode step: the final
+                # replayed token's logits are exactly the full prompt's
+                # prefill logits (token-exact by construction).
+                aligned = (n // chunk) * chunk
+                head, tail = prompt[..., :aligned], prompt[..., aligned:]
+            batch = {"tokens": jnp.asarray(head)[None]}
+            for k, v in extras.items():
+                batch[k] = jnp.asarray(v)[None]
+            mark = _dispatch.last_report()
+            logits, self.pool = self._prefill_fn(
+                self.params, batch, self.pool, jnp.asarray(slot, jnp.int32)
             )
-        logits.block_until_ready()
+            slot_idx = jnp.asarray([slot], jnp.int32)
+            for i in range(tail.shape[-1]):
+                tok = jnp.asarray(tail[..., i : i + 1][None])  # (1,1)/(1,K,1)
+                logits, self.pool = self._decode_fn(
+                    self.params, tok, self.pool, slot_idx
+                )
+        with _span("executor.wait"):
+            logits.block_until_ready()
         if _dispatch.last_report() is not mark:  # a FAµST layer dispatched
             self.faust_dispatch = _dispatch.last_report()
         return logits
 
     def decode_forward(self, slots: Sequence[int], tokens: np.ndarray):
+        with _span("executor.decode"):
+            return self._decode_forward(slots, tokens)
+
+    def _decode_forward(self, slots, tokens):
         from repro.api import dispatch as _dispatch
 
         jnp = self._jnp
-        mark = _dispatch.last_report()
-        logits, self.pool = self._decode_fn(
-            self.params,
-            jnp.asarray(tokens),
-            self.pool,
-            jnp.asarray(np.asarray(slots, np.int32)),
-        )
-        logits.block_until_ready()
+        with _span("executor.launch"):
+            mark = _dispatch.last_report()
+            logits, self.pool = self._decode_fn(
+                self.params,
+                jnp.asarray(tokens),
+                self.pool,
+                jnp.asarray(np.asarray(slots, np.int32)),
+            )
+        with _span("executor.wait"):
+            logits.block_until_ready()
         if _dispatch.last_report() is not mark:
             # decode-step decision: the steady-state serving path
             self.faust_dispatch = _dispatch.last_report()
@@ -493,20 +561,22 @@ class LMExecutor:
         """Greedy argmax of the last position — same slicing contract as
         ``Server._sample`` (seq axis is axis 1 in both logits layouts)."""
         jnp = self._jnp
-        step = logits[:, -1]  # (B, V) or (B, K, V)
-        tok = jnp.argmax(step, axis=-1).astype(jnp.int32)
-        if self.cfg.n_codebooks > 1:
-            return np.asarray(tok.reshape(tok.shape[0], self.cfg.n_codebooks, 1))
-        return np.asarray(tok.reshape(-1, 1))
+        with _span("executor.sample"):
+            step = logits[:, -1]  # (B, V) or (B, K, V)
+            tok = jnp.argmax(step, axis=-1).astype(jnp.int32)
+            if self.cfg.n_codebooks > 1:
+                return np.asarray(tok.reshape(tok.shape[0], self.cfg.n_codebooks, 1))
+            return np.asarray(tok.reshape(-1, 1))
 
     def row_finite(self, logits) -> np.ndarray:
         """Per-row all-finite mask of the last position, ``(B,)`` bool —
         the engine's NaN guard.  Reduced on device so the guard moves B
         bools per step instead of the ``(B, V)`` logits."""
         jnp = self._jnp
-        step = logits[:, -1].astype(jnp.float32)  # (B, V) or (B, K, V)
-        fin = jnp.isfinite(step).reshape(step.shape[0], -1).all(axis=-1)
-        return np.asarray(fin)
+        with _span("executor.row_finite"):
+            step = logits[:, -1].astype(jnp.float32)  # (B, V) or (B, K, V)
+            fin = jnp.isfinite(step).reshape(step.shape[0], -1).all(axis=-1)
+            return np.asarray(fin)
 
     def free(self, slot: int) -> None:
         # Cache rows are never read unless their slot is gathered live,
@@ -568,6 +638,7 @@ class Engine:
         self.done: dict[str, Request] = {}
         self.stats = EngineStats()
         self._n = 0
+        self._ticks = 0  # Engine.step calls, the engine.step span's arg
         # -- supervision policy --
         if retry_budget is None:
             retry_budget = int(os.environ.get("REPRO_RETRY_BUDGET", "2"))
@@ -663,21 +734,24 @@ class Engine:
     def step(self) -> list[str]:
         """One scheduler tick: admit while slots are free, then one decode
         step over the live batch.  Returns rids finished this tick."""
-        finished: list[str] = []
-        if self._n_deadlines:
-            self._expire(finished)
-        self._admit(finished)
-        live = self._live_by_slot()
-        if live:
-            self._decode(live, finished)
-        elif self.queue and self._maybe_blocked:
-            # nothing live and every queued request is in retry backoff:
-            # wait out the earliest not_before so run() cannot spin
-            now = self.clock()
-            wait = min(r.not_before for r in self.queue) - now
-            if wait > 0:
-                self._sleep(wait)
-        return finished
+        self._ticks += 1
+        with _span("engine.step", "step", self._ticks):
+            finished: list[str] = []
+            if self._n_deadlines:
+                self._expire(finished)
+            self._admit(finished)
+            live = self._live_by_slot()
+            if live:
+                with _span("engine.decode", "rows", len(live)):
+                    self._decode(live, finished)
+            elif self.queue and self._maybe_blocked:
+                # nothing live and every queued request is in retry backoff:
+                # wait out the earliest not_before so run() cannot spin
+                now = self.clock()
+                wait = min(r.not_before for r in self.queue) - now
+                if wait > 0:
+                    self._sleep(wait)
+            return finished
 
     def run(self, max_steps: int | None = None) -> list[str]:
         """Step until every submitted request has finished."""
@@ -731,57 +805,66 @@ class Engine:
             req = self._pop_admissible()
             if req is None:  # every queued request is in retry backoff
                 return
-            req.slot = self.allocator.alloc(req.rid)
-            notify = getattr(self.executor, "on_admit", None)
-            if notify is not None:  # e.g. FaultInjector slot→rid tracking
-                notify(req.rid, req.slot)
-            self.stats.admitted += 1
-            t0 = self.clock()
-            try:
-                logits = self.executor.prefill_forward(
-                    req.slot, req.prompt_full(), req.extras
-                )
-            except Exception as exc:  # noqa: BLE001 — supervision boundary
-                t1 = self.clock()
-                self.stats.prefill_s += t1 - t0
-                # ran=False: the fault fired before the executor touched
-                # the row, so only the allocator slot is reclaimed
-                self._step_failure([req], exc, t1, finished, ran=False)
-                return  # let the backoff elapse before re-admitting
+            prompt = req.prompt_full()
+            with _span("engine.admit", "rid", req.rid, "tokens", prompt.shape[-1]):
+                if not self._admit_one(req, prompt, finished):
+                    return  # let the backoff elapse before re-admitting
+
+    def _admit_one(self, req: Request, prompt: np.ndarray, finished: list[str]) -> bool:
+        """Prefill ``req`` into a fresh slot and sample its first token;
+        False when the prefill raised (admission pauses for the backoff)."""
+        req.slot = self.allocator.alloc(req.rid)
+        notify = getattr(self.executor, "on_admit", None)
+        if notify is not None:  # e.g. FaultInjector slot→rid tracking
+            notify(req.rid, req.slot)
+        self.stats.admitted += 1
+        t0 = self.clock()
+        try:
+            logits = self.executor.prefill_forward(req.slot, prompt, req.extras)
+        except Exception as exc:  # noqa: BLE001 — supervision boundary
             t1 = self.clock()
             self.stats.prefill_s += t1 - t0
-            tok = self.executor.sample(logits)  # (1, 1) / (1, K, 1)
-            t2 = self.clock()
-            # the prefill-sampled token is a decoded token: count it and
-            # its sampling time (the old ServeStats excluded both)
-            self.stats.decode_s += t2 - t1
-            if self.nan_guard:
-                bad = self._bad_rows(logits)
-                if bad is not None and bad[0]:
-                    self.stats.quarantined += 1
-                    self._finish_terminal(
-                        req, FAILED,
-                        "non-finite prefill logits (stream quarantined)",
-                        t2, finished,
-                    )
-                    continue
-            self._append_token(req, np.asarray(tok[0]))
-            if req.first_token_t is None:
-                req.first_token_t = t2
-                self.stats.ttft_s[req.rid] = t2 - req.arrival
-            req.state = RUNNING
-            self.running[req.rid] = req
-            if len(req.generated) >= req.max_new_tokens:
-                self._complete(req, t2, finished)
+            # ran=False: the fault fired before the executor touched
+            # the row, so only the allocator slot is reclaimed
+            self._step_failure([req], exc, t1, finished, ran=False)
+            return False
+        t1 = self.clock()
+        self.stats.prefill_s += t1 - t0
+        tok = self.executor.sample(logits)  # (1, 1) / (1, K, 1)
+        t2 = self.clock()
+        # the prefill-sampled token is a decoded token: count it and
+        # its sampling time (the old ServeStats excluded both)
+        self.stats.decode_s += t2 - t1
+        if self.nan_guard:
+            bad = self._bad_rows(logits)
+            if bad is not None and bad[0]:
+                self.stats.quarantined += 1
+                self._finish_terminal(
+                    req, FAILED,
+                    "non-finite prefill logits (stream quarantined)",
+                    t2, finished,
+                )
+                return True
+        self._append_token(req, np.asarray(tok[0]))
+        if req.first_token_t is None:
+            req.first_token_t = t2
+            self.stats.ttft_s[req.rid] = t2 - req.arrival
+        req.state = RUNNING
+        self.running[req.rid] = req
+        if len(req.generated) >= req.max_new_tokens:
+            self._complete(req, t2, finished)
+        return True
 
     def _decode(self, live: list[Request], finished: list[str]) -> None:
         slots = [r.slot for r in live]
         tokens = np.stack([r.last_token for r in live])  # (B,1)/(B,K,1)
         b = len(live)
         self.stats.steps += 1
-        self.stats.queue_depth.append(len(self.queue))
+        self.stats.queue_depth_max = max(self.stats.queue_depth_max, len(self.queue))
+        self.stats.queue_depth_sum += len(self.queue)
         self.stats.occupancy[b] = self.stats.occupancy.get(b, 0) + 1
-        self.stats.dispatch_per_step.append(self.executor.dispatch_for(b))
+        with _span("engine.dispatch_query"):
+            self.stats.dispatch_by_batch[b] = self.executor.dispatch_for(b)
         t0 = self.clock()
         try:
             logits = self.executor.decode_forward(slots, tokens)

@@ -72,7 +72,7 @@ def _assert_oracle(engine, sim, rids, prompts, budgets, skip=()):
 def _stats_key(stats):
     d = dataclasses.asdict(stats)
     d.pop("faust_dispatch", None)
-    d.pop("dispatch_per_step", None)  # None entries either way; not hashable
+    d.pop("dispatch_by_batch", None)  # None reports either way
     return d
 
 

@@ -160,7 +160,7 @@ def test_sim_determinism_bitwise():
         s = engine.stats
         return outs, (
             s.tokens_decoded, s.steps, s.admitted, s.completed, s.evicted,
-            tuple(s.queue_depth), tuple(sorted(s.occupancy.items())),
+            s.queue_depth_max, s.queue_depth_sum, tuple(sorted(s.occupancy.items())),
             tuple(sorted(s.ttft_s.items())), tuple(sorted(s.tpot_s.items())),
         ), ex.calls
 
@@ -312,9 +312,10 @@ def test_engine_vs_server_multi_codebook():
 
 
 def test_engine_live_batch_dispatch_reports():
-    """A FAµST-parameterized model gets a per-decode-step DispatchReport
-    at the *live* batch size (advisory query: doesn't clobber
-    last_report), with the autotune source recorded."""
+    """A FAµST-parameterized model gets a DispatchReport at each *live*
+    batch size it decoded at (advisory query: doesn't clobber
+    last_report), with the autotune source recorded; the executor prices
+    each batch size once."""
     from repro.api import dispatch as _dispatch
     from repro.layers.faust_linear import FaustSpec
 
@@ -333,12 +334,14 @@ def test_engine_live_batch_dispatch_reports():
     for p, b in zip(prompts, budgets):
         engine.submit(p, b)
     engine.run()
-    reps = engine.stats.dispatch_per_step
-    assert len(reps) == engine.stats.steps and all(r is not None for r in reps)
+    reps = engine.stats.dispatch_by_batch
+    assert all(r is not None for r in reps.values())
     # the decision followed the live batch as it breathed
-    seen_batches = {r.batch for r in reps}
+    seen_batches = set(reps)
     assert seen_batches == set(engine.stats.occupancy)
-    for r in reps:
+    assert sum(engine.stats.backend_counts().values()) == engine.stats.steps
+    for b, r in reps.items():
+        assert r.batch == b and ex.dispatch_for(b) is r  # priced once
         assert r.backend in r.feasible
         assert r.source == "model"  # conftest pins REPRO_AUTOTUNE=off
         assert r.bt >= 1

@@ -14,6 +14,7 @@ test worker that does not run this file never loads the TPU library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -197,3 +198,24 @@ def test_sharded_chain_unembed_4_devices(topo):
     x = _sds((128, UNEMBED[0]), jnp.bfloat16, NamedSharding(mesh, P()))
     compiled = _compile(apply, x, *flat)
     assert "all-gather" in compiled.as_text()
+
+
+def test_fused_apply_names_the_chain_kernel(one_chip):
+    """``FaustOp.apply(..., backend="fused")`` reaches the chain kernel
+    through the ``custom_vjp`` of ``kernels/ops.py``; the kernel keeps its
+    own name there, so a device trace names its events ``%faust_chain_fwd``
+    whatever jitted program encloses it."""
+    from repro.api import FaustOp
+
+    bf = _abstract_chain(*MLP, jnp.bfloat16, one_chip)
+    flat, treedef = jax.tree_util.tree_flatten(bf)
+
+    def apply(x, *flat):
+        op = FaustOp.from_blockfaust(jax.tree_util.tree_unflatten(treedef, flat))
+        return op.apply(x, backend="fused", use_kernel=True, bt=128, interpret=False)
+
+    x = _sds((128, MLP[0]), jnp.bfloat16, one_chip)
+    text = _compile(apply, x, *flat).as_text()
+    calls = re.findall(r"^\s*(%faust_chain_fwd[\w.]* = .*custom-call\(.*)$", text, re.M)
+    assert calls, "no %faust_chain_fwd instruction in the compiled program"
+    assert all('custom_call_target="tpu_custom_call"' in c for c in calls)

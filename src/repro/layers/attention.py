@@ -200,22 +200,29 @@ def decode_attention(
     q: Array,
     k_cache: Array,
     v_cache: Array,
+    k_new: Array,
+    v_new: Array,
     *,
     q_position: Array,
     kv_positions: Array,
     window: int | None = None,
     scale: float | None = None,
 ) -> Array:
-    """Single-step decode: q (B,1,H,D) over the cache (B,KH,L,D).
+    """Single-step decode: q (B,1,H,D) over the cached tokens (B,KH,L,D)
+    and its own key and value ``k_new``/``v_new`` (B,KH,1,D), which are
+    kept apart from the cache: the cache is read, never written here.
 
     Direct stable softmax (no chunk scan) — with a seq-sharded cache the
     max/sum reductions lower to partial reductions + all-reduce (SP decode).
     ``kv_positions`` carries the *global* position of every cache row
-    (ring-buffer caches pass their unrolled positions); invalid rows are
-    masked out by causality.  Both position arguments may carry a leading
-    batch dim (``q_position (B,Sq)``, ``kv_positions (B,L)``) — the
-    slot-paged serving pool decodes rows at independent positions — or
-    be batch-free (legacy shared-position decode).
+    (ring-buffer caches pass their unrolled positions); cached rows at or
+    after the query's position, or outside ``window``, are masked out.  The
+    new token's own score joins the cached scores in one softmax and its
+    value is added to theirs — the arithmetic of writing the entry into
+    the cache first and attending over it.  Both position arguments may
+    carry a leading batch dim (``q_position (B,Sq)``, ``kv_positions
+    (B,L)``) — the slot-paged serving pool decodes rows at independent
+    positions — or be batch-free (legacy shared-position decode).
 
     Perf notes (EXPERIMENTS.md §Perf iteration 2): the cache layout is
     (B, KH, L, D) — the dot's native batch-major layout, so no per-step
@@ -227,20 +234,33 @@ def decode_attention(
     """
     b, sq, h, d = q.shape
     _, kh, l, _ = k_cache.shape
+    assert sq == 1, "decode attends one new token per row"
     g = h // kh
     scale = d**-0.5 if scale is None else scale
     qg = q.reshape(b, sq, kh, g, d).transpose(0, 2, 3, 1, 4)  # (B,KH,G,Sq,D)
     qg = qg.reshape(b, kh, g * sq, d).astype(k_cache.dtype)
-    s = jnp.einsum("bhqd,bhcd->bhqc", qg, k_cache)  # bf16 dot, no transpose
-    s = s.astype(jnp.float32).reshape(b, kh, g, sq, l) * scale
+
+    def scores(keys):  # bf16 dot, no transpose → (B,KH,G,Sq,C) f32
+        s = jnp.einsum("bhqd,bhcd->bhqc", qg, keys)
+        return s.astype(jnp.float32).reshape(b, kh, g, sq, keys.shape[2]) * scale
+
+    s = scores(k_cache)
     bias = _mask_bias(q_position, kv_positions, True, window)  # ([B,]Sq,L)
     s = s + (bias[:, None, None] if bias.ndim == 3 else bias)
-    m = jnp.max(s, axis=-1, keepdims=True)
+    s_new = scores(k_new)  # (B,KH,G,Sq,1): the new token, always visible
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s_new)
     p = jnp.exp(s - m)
-    p = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(v_cache.dtype)
-    o = jnp.einsum(
-        "bhqc,bhcd->bhqd", p.reshape(b, kh, g * sq, l), v_cache
-    )  # (B,KH,G·Sq,D)
+    p_new = jnp.exp(s_new - m)
+    denom = jnp.sum(p, axis=-1, keepdims=True) + p_new
+
+    def mix(weights, values):  # (B,KH,G,Sq,C)·(B,KH,C,D) → (B,KH,G·Sq,D) f32
+        w = (weights / denom).astype(values.dtype)
+        return jnp.einsum(
+            "bhqc,bhcd->bhqd", w.reshape(b, kh, g * sq, -1), values,
+            preferred_element_type=jnp.float32,
+        )
+
+    o = mix(p, v_cache) + mix(p_new, v_new)
     o = o.reshape(b, kh, g, sq, d).transpose(0, 3, 1, 2, 4)
     return o.reshape(b, sq, h, d).astype(q.dtype)
 
@@ -258,19 +278,27 @@ class KVCache(NamedTuple):
     ``pos`` is per-row: shape ``(B,)``, the number of tokens each batch
     row has seen.  The serving engine's slot-paged pool relies on this —
     every batch row is an independently-positioned cache *slot*, so
-    requests of uneven length share one static-shape cache and decode
-    steps gather/scatter rows by slot index (``models/lm.py``
-    ``gather_cache_slots``/``scatter_cache_slots``).  A scalar ``pos``
-    (legacy all-rows-share semantics) still broadcasts correctly through
-    every function here."""
+    requests of uneven length share one static-shape cache; a decode step
+    reads the whole pool and writes each live row's new entry in place
+    (``models/lm.py`` ``scatter_cache_slots``, through
+    :func:`kv_cache_write`).  A scalar ``pos`` (legacy all-rows-share
+    semantics) still broadcasts correctly through every function here."""
 
     k: Array  # (B, KH, capacity, D)
     v: Array
     pos: Array  # (B,) int32 — tokens seen per row (scalar = shared)
 
     @property
-    def capacity(self) -> int:
-        return self.k.shape[2]
+    def capacity(self) -> int:  # also under leading (stacked-layer) axes
+        return self.k.shape[-2]
+
+
+class KVEntry(NamedTuple):
+    """One decode step's new key and value per row, ``(B, KH, 1, D)`` in
+    the cache dtype — what a decode step adds to a :class:`KVCache`."""
+
+    k: Array
+    v: Array
 
 
 def kv_cache_init(b: int, capacity: int, kh: int, d: int, dtype=jnp.bfloat16) -> KVCache:
@@ -281,21 +309,38 @@ def kv_cache_init(b: int, capacity: int, kh: int, d: int, dtype=jnp.bfloat16) ->
     )
 
 
-def kv_cache_update_decode(cache: KVCache, k_new: Array, v_new: Array) -> KVCache:
-    """Insert one token (B,1,KH,D) at each row's pos (mod capacity for
-    ring buffers) — a per-row scatter, since slot positions differ."""
-    idx = cache.pos % cache.capacity
-    k_t = k_new.astype(cache.k.dtype).transpose(0, 2, 1, 3)  # (B,KH,1,D)
-    v_t = v_new.astype(cache.v.dtype).transpose(0, 2, 1, 3)
-    if idx.ndim == 0:  # legacy scalar pos: one dynamic slice for all rows
-        k = jax.lax.dynamic_update_slice_in_dim(cache.k, k_t, idx, axis=2)
-        v = jax.lax.dynamic_update_slice_in_dim(cache.v, v_t, idx, axis=2)
-    else:
-        b = cache.k.shape[0]
-        rows = jnp.arange(b)
-        k = cache.k.at[rows, :, idx].set(k_t[:, :, 0])
-        v = cache.v.at[rows, :, idx].set(v_t[:, :, 0])
-    return KVCache(k, v, cache.pos + 1)
+def kv_cache_write(cache: KVCache, new: KVEntry, live: Array) -> KVCache:
+    """Insert each live row's new entry at its pos (mod capacity for ring
+    buffers) and advance its pos; rows where ``live (B,)`` is False keep
+    their entries and pos bit for bit.  Leaves may carry leading axes
+    ahead of the batch (a model's stacked layers, ``new`` likewise), over
+    which each row's pos is the same.  One in-place
+    ``dynamic_update_slice`` per row: the cache is never copied."""
+    b, cap = cache.pos.shape[-1], cache.capacity
+    lead = cache.pos.ndim - 1
+    row_pos = cache.pos.reshape(-1, b)[0]
+
+    def put(row, kv):
+        start = (0,) * lead + (row, 0, row_pos[row] % cap, 0)
+        out = []
+        for buf, entry in zip(kv, new):
+            val = jax.lax.dynamic_slice_in_dim(entry, row, 1, axis=lead)
+            cur = jax.lax.dynamic_slice(buf, start, val.shape)
+            out.append(jax.lax.dynamic_update_slice(buf, jnp.where(live[row], val, cur), start))
+        return tuple(out)
+
+    k, v = jax.lax.fori_loop(0, b, put, (cache.k, cache.v))
+    return KVCache(k, v, cache.pos + live.astype(cache.pos.dtype))
+
+
+def kv_cache_update_decode(cache: KVCache, new: KVEntry) -> KVCache:
+    """Insert one token's entry (B,KH,1,D) into every row of ``cache``."""
+    if cache.pos.ndim == 0:  # legacy scalar pos: one dynamic slice for all rows
+        idx = cache.pos % cache.capacity
+        k = jax.lax.dynamic_update_slice_in_dim(cache.k, new.k, idx, axis=2)
+        v = jax.lax.dynamic_update_slice_in_dim(cache.v, new.v, idx, axis=2)
+        return KVCache(k, v, cache.pos + 1)
+    return kv_cache_write(cache, new, jnp.ones(cache.pos.shape, bool))
 
 
 def kv_cache_positions(cache: KVCache) -> Array:
@@ -409,18 +454,31 @@ def attn_prefill(p: dict, x: Array, spec: AttnSpec, cache: KVCache, chunk: int =
     return o.reshape(b, s, -1) @ p["wo"], new_cache
 
 
-def attn_decode(p: dict, x: Array, spec: AttnSpec, cache: KVCache):
-    """One-token decode step: x (B,1,d).  Per-row cache positions give
-    per-row rope/mask positions — (B,S); legacy scalar pos gives (S,)."""
+def attn_decode_entry(p: dict, x: Array, spec: AttnSpec, cache: KVCache):
+    """One-token decode step that reads ``cache`` and leaves it as it is:
+    x (B,1,d) attends over the cached tokens with its own K/V kept apart
+    (:func:`decode_attention`).  Returns ``(y, entry)``, the
+    :class:`KVEntry` for the caller to write.  Per-row cache positions
+    give per-row rope/mask positions — (B,S); legacy scalar pos gives (S,)."""
     b, s, _ = x.shape
-    pos = cache.pos
-    positions = pos[..., None] + jnp.arange(s)
+    positions = cache.pos[..., None] + jnp.arange(s)
     q, k, v = attn_qkv(p, x, spec, positions)
-    cache = kv_cache_update_decode(cache, k, v)
+    new = KVEntry(
+        k.astype(cache.k.dtype).transpose(0, 2, 1, 3),  # (B,KH,1,D)
+        v.astype(cache.v.dtype).transpose(0, 2, 1, 3),
+    )
+    # the written cache would hold the last `capacity` tokens: mask as it would
+    window = cache.capacity if spec.window is None else min(spec.window, cache.capacity)
     o = decode_attention(
-        q, cache.k, cache.v,
+        q, cache.k, cache.v, new.k, new.v,
         q_position=positions,
         kv_positions=kv_cache_positions(cache),
-        window=spec.window, scale=spec.scale,
+        window=window, scale=spec.scale,
     )
-    return o.reshape(b, s, -1) @ p["wo"], cache
+    return o.reshape(b, s, -1) @ p["wo"], new
+
+
+def attn_decode(p: dict, x: Array, spec: AttnSpec, cache: KVCache):
+    """One-token decode step that returns the cache with its entry written."""
+    y, new = attn_decode_entry(p, x, spec, cache)
+    return y, kv_cache_update_decode(cache, new)

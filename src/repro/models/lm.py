@@ -15,6 +15,8 @@ Entry points:
   init_model / param_axes      — parameters (+ logical sharding axes)
   train_loss                   — next-token CE (+ MoE aux), fp32 logits
   prefill / decode_step        — serving path with per-layer caches
+  decode_delta / scatter_cache_slots — decode that reads the caches and
+                                 the write of its update (the slot pool)
   make_caches                  — cache pytree (abstract-init friendly)
 
 Modality frontends (per spec, stubs): "vlm" consumes precomputed patch
@@ -180,37 +182,49 @@ def _layer_cache(cfg: ArchConfig, kind: str, batch: int, cache_len: int, dtype):
 
 
 def make_caches(cfg: ArchConfig, batch: int, cache_len: int, dtype=jnp.bfloat16):
-    stages = []
-    for repeat, unit in cfg.stages:
-        stage = []
-        for kind in unit:
-            per = [_layer_cache(cfg, kind, batch, cache_len, dtype) for _ in range(repeat)]
-            stage.append(jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per))
-        stages.append(stage)
-    return stages
+    """Every layer's cache, stacked over its stage's repeat.  Each cache
+    starts as zeros, so each leaf is allocated stacked at once: stacking
+    per-layer arrays eagerly held the whole pool three times over."""
+
+    def stacked(repeat, kind):
+        one = jax.eval_shape(lambda: _layer_cache(cfg, kind, batch, cache_len, dtype))
+        return jax.tree_util.tree_map(lambda a: jnp.zeros((repeat,) + a.shape, a.dtype), one)
+
+    return [[stacked(repeat, kind) for kind in unit] for repeat, unit in cfg.stages]
 
 
 # Every cache leaf is stacked over the scan repeat (axis 0), so the batch
 # dim — the serving engine's *slot* dim — is axis 1 uniformly: KVCache.k
 # (repeat, B, KH, cap, D), KVCache.pos (repeat, B), MambaCache.ssm
 # (repeat, B, H, P, N), …  The slot-paged pool (runtime/engine.py) keeps
-# one make_caches(cfg, n_slots, max_len) pytree alive and gathers the
-# live requests' rows into a (repeat, B_live, …) cache per decode step.
+# one make_caches(cfg, n_slots, max_len) pytree alive and decodes all of
+# its rows where they lie: decode_delta reads the pool, and
+# scatter_cache_slots writes the step's update into the live rows.
 _CACHE_BATCH_AXIS = 1
 
 
-def gather_cache_slots(caches, slot_idx: Array):
-    """Select cache rows ``slot_idx (B,)`` from a slot pool → a live-batch
-    cache pytree with batch size ``len(slot_idx)``."""
-    return jax.tree_util.tree_map(
-        lambda a: jnp.take(a, slot_idx, axis=_CACHE_BATCH_AXIS), caches
-    )
+def scatter_cache_slots(pool, update, slot_idx: Array):
+    """Write one decode step's ``update`` (:func:`decode_delta` over
+    ``pool``) into pool rows ``slot_idx (M,)``, in place when ``pool`` is
+    donated: each listed row's new K/V entry at its ``pos % capacity``
+    and its ``pos`` advanced; Mamba rows take their whole new state.
+    Indices past the pool's rows are dropped, so a fixed-width
+    ``slot_idx`` lists the live rows and pads; rows not listed keep their
+    cache and ``pos`` bit for bit."""
+    n = _first_cache_pos(pool).shape[0]
+    live = jnp.zeros((n,), bool).at[slot_idx].set(True, mode="drop")
 
+    def write(cache, upd):
+        if isinstance(cache, A.KVCache):  # upd: KVEntry, stacked over repeat
+            return A.kv_cache_write(cache, upd, live)
+        return jax.tree_util.tree_map(  # whole states, stacked over repeat
+            lambda p, u: jnp.where(live.reshape((1, n) + (1,) * (p.ndim - 2)), u, p),
+            cache,
+            upd,
+        )
 
-def scatter_cache_slots(pool, caches, slot_idx: Array):
-    """Write a live-batch cache pytree back into pool rows ``slot_idx``."""
     return jax.tree_util.tree_map(
-        lambda p, a: p.at[:, slot_idx].set(a), pool, caches
+        write, pool, update, is_leaf=lambda c: isinstance(c, (A.KVCache, M.MambaCache))
     )
 
 
@@ -257,7 +271,7 @@ def _apply_layer(
     elif mode == _Mode.PREFILL:
         y, new_cache = A.attn_prefill(lp["attn"], h, spec, cache, chunk)
     else:
-        y, new_cache = A.attn_decode(lp["attn"], h, spec, cache)
+        y, new_cache = A.attn_decode_entry(lp["attn"], h, spec, cache)
     x = x + shard_act(y.astype(x.dtype), "batch", "seq", None)
 
     h = apply_norm(cfg.norm, lp["norm2"], x)
@@ -281,7 +295,9 @@ def _apply_layer(
 
 
 def _run_stages(params, cfg: ArchConfig, x: Array, mode: str, caches):
-    """Scan every stage; returns (x, aux, new_caches)."""
+    """Scan every stage; returns (x, aux, new_caches).  In decode mode the
+    caches are read only and each layer emits what the step changes (the
+    ``update`` of :func:`decode_delta`) in place of its cache."""
     aux = jnp.zeros((), jnp.float32)
     shared = params.get("shared")
     new_caches = []
@@ -399,14 +415,27 @@ def prefill(params, cfg: ArchConfig, batch: dict, caches):
     return logits, new_caches
 
 
-def decode_step(params, cfg: ArchConfig, tokens: Array, caches):
-    """tokens: (B,1) (or (B,K,1) audio). Returns (logits, new_caches)."""
+def decode_delta(params, cfg: ArchConfig, tokens: Array, caches):
+    """One decode step that reads ``caches`` and leaves them as they are.
+
+    tokens: (B,1) (or (B,K,1) audio).  Returns ``(logits, update)``:
+    ``update`` mirrors ``caches`` layer by layer, holding for attention
+    layers the new token's :class:`~repro.layers.attention.KVEntry`
+    (repeat, B, KH, 1, D) and for Mamba layers the whole new state, which
+    is rewritten every step — what :func:`scatter_cache_slots` writes."""
     pos0 = _first_cache_pos(caches)
     x = _embed_tokens(params, cfg, tokens, pos0)
     x = shard_act(x, "batch", None, None)
-    x, _, new_caches = _run_stages(params, cfg, x, _Mode.DECODE, caches)
+    x, _, update = _run_stages(params, cfg, x, _Mode.DECODE, caches)
     x = apply_norm(cfg.norm, params["final_norm"], x)
-    return _logits(params, cfg, x), new_caches
+    return _logits(params, cfg, x), update
+
+
+def decode_step(params, cfg: ArchConfig, tokens: Array, caches):
+    """tokens: (B,1) (or (B,K,1) audio). Returns (logits, new_caches)."""
+    logits, update = decode_delta(params, cfg, tokens, caches)
+    rows = jnp.arange(tokens.shape[0])
+    return logits, scatter_cache_slots(caches, update, rows)
 
 
 def _first_cache_pos(caches) -> Array:

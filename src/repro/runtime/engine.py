@@ -25,15 +25,19 @@ module replaces it with a proper engine:
   and the FAµST dispatch decision at each live batch size.  Nothing in
   it grows with the number of steps served.
 
-**Static shapes.** ``lm.prefill`` / ``lm.decode_step`` never see a
-dynamic shape: the cache pool keeps the slot dim at ``n_slots``; a decode
-step gathers the live slots' rows (``lm.gather_cache_slots``) into a
-``(repeat, B_live, …)`` cache, steps it, and scatters the rows back.
-Per-slot position tracking (``KVCache.pos``/``MambaCache.pos`` are per
-row) replaces left-padding: a reused slot simply restarts its row's
+**Static shapes.** ``lm.prefill`` / ``lm.decode_delta`` never see a
+dynamic shape: the cache pool keeps the slot dim at ``n_slots``, and a
+decode step runs every pool row where it lies (``lm.decode_delta`` reads
+the pool, free slots fed a dummy token) and writes back only what the
+token changed, for the live rows only (``lm.scatter_cache_slots``: one
+new K/V entry per attention layer and row, the small Mamba states
+whole).  A free slot's cache and ``pos`` stay as they were.  Per-slot
+position tracking (``KVCache.pos``/``MambaCache.pos`` are per row)
+replaces left-padding: a reused slot simply restarts its row's
 positions, and stale entries beyond the new occupant's ``pos`` are
-masked by the ring-attention window math.  jit recompiles only per
-distinct live batch size / prompt length, not per slot or schedule.
+masked by the ring-attention window math.  jit compiles one decode
+program per pool and one prefill per prompt length, not per live batch
+size, slot or schedule.
 
 **Live-batch dispatch.** Each decode step consults the dispatch layer at
 the *live* batch size (:meth:`repro.api.FaustOp.dispatch_for`,
@@ -101,7 +105,9 @@ one check and builds nothing.  The spans, outermost first:
   (args ``rid``, ``tokens``).
 * ``engine.decode`` — the decode half of the tick (arg ``rows``), with
   ``engine.dispatch_query`` around the advisory dispatch lookup.
-* ``executor.prefill`` / ``executor.decode`` — the forward call, split
+* ``executor.prefill`` / ``executor.decode`` — the forward call
+  (``executor.decode`` with args ``rows``, the live rows, and
+  ``pool_rows``, the rows the decode program computes), split
   into ``executor.launch`` (argument conversion, host-to-device copies and
   the jitted calls until they return, the ragged-tail replay included)
   and ``executor.wait`` (blocking on the logits).
@@ -284,6 +290,7 @@ class EngineStats:
     # decode-step observability, constant in size however long it serves
     queue_depth_max: int = 0  # deepest queue seen at a decode step
     queue_depth_sum: int = 0  # queue depth summed over decode steps
+    decode_masked_rows: int = 0  # rows decoded for free slots, over decode steps
     occupancy: dict = dataclasses.field(default_factory=dict)  # B_live -> steps
     dispatch_by_batch: dict = dataclasses.field(default_factory=dict)  # B_live -> last report
     # per-request latency (seconds, under the engine's clock)
@@ -324,6 +331,8 @@ class Executor(Protocol):
     """
 
     n_slots: int
+    # Optional ``pool_rows``: the rows every decode step computes whatever
+    # the live batch (LMExecutor: the whole pool); absent, the live rows.
 
     def prefill_forward(self, slot: int, prompt: np.ndarray, extras: dict):
         """Run the prompt through the model into cache slot ``slot``;
@@ -358,9 +367,12 @@ class LMExecutor:
       ``dynamic_update_slice`` along the slot axis — ``slot`` is traced,
       so admissions into different slots share one compilation (one per
       distinct prompt length).
-    * ``_decode_fn(params, tokens, pool, slot_idx)`` gathers the live
-      rows, steps them, scatters back — one compilation per distinct
-      live batch size.
+    * ``_decode_fn(params, tokens, pool, slot_idx)`` decodes all
+      ``n_slots`` rows of the pool where they lie (``tokens`` in pool-row
+      order, a dummy token for free slots) and writes the new entries of
+      the rows ``slot_idx`` lists — the live slots, padded with
+      ``n_slots`` — returning the logits in ``slot_idx`` order: one
+      compilation per pool.
 
     Both donate the pool, so the slot pool is updated in place
     buffer-wise.  The FAµST dispatch staged while tracing is captured
@@ -382,6 +394,7 @@ class LMExecutor:
         self._jnp, self._lm = jnp, lm
         self._act_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         self.pool = lm.make_caches(cfg, n_slots, max_len, dtype=self._act_dtype)
+        self.pool_rows = n_slots  # rows every decode step computes
         self.faust_dispatch = None  # last decision staged into a trace
         self._faust_op = self._build_faust_op()
         self._dispatch_memo: dict = {}  # live batch -> advisory report
@@ -403,10 +416,9 @@ class LMExecutor:
 
         def _decode(params, tokens, pool, slot_idx):
             with shd.use_rules(mesh, cfg.decode_policy()):
-                caches = lm.gather_cache_slots(pool, slot_idx)
-                logits, caches = lm.decode_step(params, cfg, tokens, caches)
-                pool = lm.scatter_cache_slots(pool, caches, slot_idx)
-                return logits, pool
+                logits, update = lm.decode_delta(params, cfg, tokens, pool)
+                pool = lm.scatter_cache_slots(pool, update, slot_idx)
+                return jnp.take(logits, slot_idx, axis=0, mode="clip"), pool
 
         self._prefill_fn = jax.jit(_prefill, donate_argnums=2)
         self._decode_fn = jax.jit(_decode, donate_argnums=2)
@@ -522,12 +534,9 @@ class LMExecutor:
             logits, self.pool = self._prefill_fn(
                 self.params, batch, self.pool, jnp.asarray(slot, jnp.int32)
             )
-            slot_idx = jnp.asarray([slot], jnp.int32)
             for i in range(tail.shape[-1]):
-                tok = jnp.asarray(tail[..., i : i + 1][None])  # (1,1)/(1,K,1)
-                logits, self.pool = self._decode_fn(
-                    self.params, tok, self.pool, slot_idx
-                )
+                tok = tail[..., i : i + 1][None]  # (1,1)/(1,K,1)
+                logits = self._decode_pool([slot], tok)
         with _span("executor.wait"):
             logits.block_until_ready()
         if _dispatch.last_report() is not mark:  # a FAµST layer dispatched
@@ -535,21 +544,29 @@ class LMExecutor:
         return logits
 
     def decode_forward(self, slots: Sequence[int], tokens: np.ndarray):
-        with _span("executor.decode"):
+        with _span("executor.decode", "rows", len(slots), "pool_rows", self.pool_rows):
             return self._decode_forward(slots, tokens)
+
+    def _decode_pool(self, slots, tokens):
+        """One pool-wide decode step for the live rows ``slots``; returns
+        their logits in ``slots`` order."""
+        jnp, n, b = self._jnp, self.n_slots, len(slots)
+        tokens = np.asarray(tokens)
+        pool_tokens = np.zeros((n,) + tokens.shape[1:], tokens.dtype)
+        pool_tokens[slots] = tokens  # free slots decode token 0, unwritten
+        slot_idx = np.full(n, n, np.int32)  # padding past the pool is dropped
+        slot_idx[:b] = slots
+        logits, self.pool = self._decode_fn(
+            self.params, jnp.asarray(pool_tokens), self.pool, jnp.asarray(slot_idx)
+        )
+        return logits if b == n else logits[:b]
 
     def _decode_forward(self, slots, tokens):
         from repro.api import dispatch as _dispatch
 
-        jnp = self._jnp
         with _span("executor.launch"):
             mark = _dispatch.last_report()
-            logits, self.pool = self._decode_fn(
-                self.params,
-                jnp.asarray(tokens),
-                self.pool,
-                jnp.asarray(np.asarray(slots, np.int32)),
-            )
+            logits = self._decode_pool(slots, tokens)
         with _span("executor.wait"):
             logits.block_until_ready()
         if _dispatch.last_report() is not mark:
@@ -579,8 +596,9 @@ class LMExecutor:
             return np.asarray(fin)
 
     def free(self, slot: int) -> None:
-        # Cache rows are never read unless their slot is gathered live,
-        # and a reuse prefill overwrites pos — nothing to scrub.
+        # A free row is decoded with a dummy token but never written, its
+        # logits are dropped, and a reuse prefill overwrites the row and
+        # its pos — nothing to scrub.
         return None
 
 
@@ -863,6 +881,7 @@ class Engine:
         self.stats.queue_depth_max = max(self.stats.queue_depth_max, len(self.queue))
         self.stats.queue_depth_sum += len(self.queue)
         self.stats.occupancy[b] = self.stats.occupancy.get(b, 0) + 1
+        self.stats.decode_masked_rows += getattr(self.executor, "pool_rows", b) - b
         with _span("engine.dispatch_query"):
             self.stats.dispatch_by_batch[b] = self.executor.dispatch_for(b)
         t0 = self.clock()

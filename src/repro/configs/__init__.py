@@ -14,6 +14,7 @@ ARCH_IDS = [
     "granite_moe_3b",
     "musicgen_medium",
     "zamba2_7b",
+    "nemotron_h_47b",
 ]
 
 ALIASES = {
@@ -27,6 +28,7 @@ ALIASES = {
     "granite-moe-3b-a800m": "granite_moe_3b",
     "musicgen-medium": "musicgen_medium",
     "zamba2-7b": "zamba2_7b",
+    "nemotron-h-47b": "nemotron_h_47b",
 }
 
 

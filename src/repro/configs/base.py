@@ -21,6 +21,7 @@ from repro.layers.moe import MoESpec
 #   "local"  — sliding-window attention + dense FFN
 #   "moe"    — global attention + MoE FFN
 #   "ssm"    — mamba2 block (no FFN)
+#   "ssm_mlp" — mamba2 block, then a dense FFN block (nemotron-h's "M-")
 #   "shared" — zamba2's shared transformer block (params reused)
 Stage = tuple[int, tuple[str, ...]]
 
@@ -140,12 +141,14 @@ def param_count(cfg: ArchConfig) -> int:
             if cfg.moe.shared_expert_ff:
                 moe_p += glu * d * cfg.moe.shared_expert_ff
             total += attn_p + moe_p
-        elif kind == "ssm":
+        elif kind in ("ssm", "ssm_mlp"):
             s = cfg.ssm
             din = s.d_inner
             total += d * (2 * din + 2 * s.n_groups * s.d_state + s.n_heads)
             total += s.d_conv * (din + 2 * s.n_groups * s.d_state)
             total += din * d + 3 * s.n_heads + din
+            if kind == "ssm_mlp":
+                total += mlp_p
         elif kind == "shared":
             if not shared_seen:
                 total += attn_p + mlp_p

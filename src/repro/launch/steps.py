@@ -62,7 +62,7 @@ def cache_shardings(cfg: ArchConfig, mesh: Mesh, policy: ShardingPolicy, cell: S
     for repeat, unit in cfg.stages:
         stage = []
         for kind in unit:
-            if kind == "ssm":
+            if kind in ("ssm", "ssm_mlp"):
                 stage.append(mamba_sharding())
             else:
                 cap = cell.seq_len

@@ -251,6 +251,19 @@ def mamba2_apply(
                 jnp.full(cache.pos.shape, s, jnp.int32),
             )
 
-    y = rmsnorm(p["norm_w"], y * jax.nn.silu(z))
+    y = gated_norm(p["norm_w"], y, z, g)
     out = y @ p["out_proj"]
     return out, new_cache
+
+
+def gated_norm(w: Array, y: Array, z: Array, groups: int) -> Array:
+    """RMSNorm of ``y·silu(z)`` over each of ``groups`` equal slices of the
+    ``d_inner`` channels (one per B/C group), with the ``1 + w`` gain.
+    One group keeps the whole-width expression: under jit a reshaped
+    bf16 graph fuses, and so rounds, differently."""
+    gated = y * jax.nn.silu(z)
+    if groups == 1:
+        return rmsnorm(w, gated)
+    shape = gated.shape
+    split = shape[:-1] + (groups, shape[-1] // groups)
+    return rmsnorm(w.reshape(groups, -1), gated.reshape(split)).reshape(shape)

@@ -1,4 +1,4 @@
-"""Unified decoder LM covering all 10 assigned architectures.
+"""Unified decoder LM covering all 11 assigned architectures.
 
 The per-layer structure is described by ``cfg.stages`` — (repeat, unit)
 pairs where a *unit* is a tuple of layer kinds executed inside one
@@ -7,9 +7,11 @@ mamba+shared-block pattern scan over their periodic repeat units, keeping
 the HLO small at 62–81 layers).
 
 Layer kinds: "attn" (global attention + FFN), "local" (sliding window +
-FFN), "moe" (attention + MoE), "ssm" (mamba2), "shared" (zamba2's shared
-transformer block — parameters live outside the scan and are reused; each
-occurrence still owns its KV cache).
+FFN), "moe" (attention + MoE), "ssm" (mamba2), "ssm_mlp" (a mamba2 block
+then an FFN block, each with its own norm and residual: nemotron-h's
+``M-`` pair), "shared" (zamba2's shared transformer block — parameters
+live outside the scan and are reused; each occurrence still owns its KV
+cache).
 
 Entry points:
   init_model / param_axes      — parameters (+ logical sharding axes)
@@ -82,12 +84,16 @@ def _dtype(cfg: ArchConfig):
 def _layer_init(key: jax.Array, cfg: ArchConfig, kind: str) -> dict:
     dt = _dtype(cfg)
     d = cfg.d_model
-    if kind == "ssm":
+    if kind in ("ssm", "ssm_mlp"):
         k1, k2 = jax.random.split(key)
-        return {
+        p = {
             "norm": norm_init(cfg.norm, d, dt),
             "mamba": M.mamba2_init(k1, cfg.ssm, dt),
         }
+        if kind == "ssm_mlp":
+            p["norm2"] = norm_init(cfg.norm, d, dt)
+            p["mlp"] = mlp_init(k2, d, cfg.d_ff, cfg.act, dt, faust=cfg.faust_mlp)
+        return p
     if kind == "shared":
         return {}  # params live outside the scan
     ks = jax.random.split(key, 4)
@@ -173,7 +179,7 @@ def abstract_params(cfg: ArchConfig):
 
 
 def _layer_cache(cfg: ArchConfig, kind: str, batch: int, cache_len: int, dtype):
-    if kind == "ssm":
+    if kind in ("ssm", "ssm_mlp"):
         return M.mamba_cache_init(batch, cfg.ssm, dtype)
     cap = cache_len
     if kind == "local" and cfg.window is not None:
@@ -250,7 +256,7 @@ def _apply_layer(
     cache,
 ):
     chunk = cfg.attn_chunk
-    if kind == "ssm":
+    if kind in ("ssm", "ssm_mlp"):
         h = apply_norm(cfg.norm, lp["norm"], x)
         if mode == _Mode.TRAIN:
             y, new_cache = M.mamba2_apply(lp["mamba"], h, cfg.ssm, None, False)
@@ -258,7 +264,10 @@ def _apply_layer(
             y, new_cache = M.mamba2_apply(lp["mamba"], h, cfg.ssm, cache, False)
         else:
             y, new_cache = M.mamba2_apply(lp["mamba"], h, cfg.ssm, cache, True)
-        return x + y.astype(x.dtype), aux, new_cache
+        x = x + y.astype(x.dtype)
+        if kind == "ssm_mlp":
+            x = x + _mlp(cfg, lp, x)
+        return x, aux, new_cache
 
     if kind == "shared":
         lp = shared_params
@@ -274,8 +283,8 @@ def _apply_layer(
         y, new_cache = A.attn_decode_entry(lp["attn"], h, spec, cache)
     x = x + shard_act(y.astype(x.dtype), "batch", "seq", None)
 
-    h = apply_norm(cfg.norm, lp["norm2"], x)
     if kind == "moe":
+        h = apply_norm(cfg.norm, lp["norm2"], x)
         # §Perf iteration 4: optionally gather the sequence dim at the MoE
         # boundary — routing sorts and the (B,E,C,·) expert einsums otherwise
         # conflict with context-parallel seq sharding and XLA partial-sum
@@ -285,13 +294,19 @@ def _apply_layer(
             h = shard_act(h, "batch", None, None)
         y, layer_aux = MOE.moe_apply(lp["moe"], h, cfg.moe)
         aux = aux + layer_aux
-    else:
-        y = mlp_apply(
-            lp["mlp"], h, cfg.act,
-            faust=cfg.faust_mlp, d_model=cfg.d_model, d_ff=cfg.d_ff,
-        )
-    x = x + shard_act(y.astype(x.dtype), "batch", "seq", None)
-    return x, aux, new_cache
+        x = x + shard_act(y.astype(x.dtype), "batch", "seq", None)
+        return x, aux, new_cache
+    return x + _mlp(cfg, lp, x), aux, new_cache
+
+
+def _mlp(cfg: ArchConfig, lp: dict, x: Array) -> Array:
+    """The FFN block's residual branch: ``mlp(norm2(x))``."""
+    h = apply_norm(cfg.norm, lp["norm2"], x)
+    y = mlp_apply(
+        lp["mlp"], h, cfg.act,
+        faust=cfg.faust_mlp, d_model=cfg.d_model, d_ff=cfg.d_ff,
+    )
+    return shard_act(y.astype(x.dtype), "batch", "seq", None)
 
 
 def _run_stages(params, cfg: ArchConfig, x: Array, mode: str, caches):

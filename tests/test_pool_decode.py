@@ -5,7 +5,8 @@ writes back only what one token changes (``lm.decode_delta`` +
 ``lm.scatter_cache_slots``).  Pinned here against the path it replaced —
 gather the live rows, ``lm.decode_step`` them, scatter the whole rows
 back — for a global-attention model, a sliding-window model whose ring
-buffer wraps, and a Mamba hybrid:
+buffer wraps, and two Mamba hybrids (Mamba-2 beside a shared attention
+block, and Mamba-2 over 8 groups with FFN pairs beside GQA attention):
 
 * token-exact over 24 steps, with the live caches equal at the end;
 * free slots' K, V, Mamba state and ``pos`` bit-identical across steps;
@@ -52,10 +53,16 @@ def _mamba_hybrid():
     return _deep(cfg, ("ssm", "shared"))
 
 
+def _nemotron_h():
+    cfg = get_smoke("nemotron_h_47b")  # Mamba-2 over 8 groups with FFN pairs, and GQA
+    return _deep(cfg, ("ssm_mlp", "attn"))
+
+
 CASES = {
     "global_attn": _global_attn,
     "sliding_window": _sliding_window,
     "mamba_hybrid": _mamba_hybrid,
+    "nemotron_h": _nemotron_h,
 }
 
 

@@ -111,8 +111,14 @@ one check and builds nothing.  The spans, outermost first:
   into ``executor.launch`` (argument conversion, host-to-device copies and
   the jitted calls until they return, the ragged-tail replay included)
   and ``executor.wait`` (blocking on the logits).
-* ``executor.sample`` / ``executor.row_finite`` — greedy argmax and the
-  NaN guard, each with its readback to the host.
+* ``executor.sample`` (arg ``fused``) — the step's one readback: the
+  decode and prefill programs pick each row's greedy tokens and its
+  all-finite flag themselves, and ``sample`` copies those few int32s to
+  the host (``fused`` 1).  Logits that are not what the last step
+  returned (the ``FaultInjector``'s poisoned copy, a slice) are reduced
+  eagerly instead, with a readback of their own (``fused`` 0).
+* ``executor.row_finite`` (arg ``fused``) — the NaN guard, read from the
+  same host copy with no further sync (or reduced eagerly, as above).
 
 Time inside ``engine.step`` but outside every ``executor.*`` span is the
 engine's own bookkeeping (scheduling, the dispatch query, token append,
@@ -374,6 +380,13 @@ class LMExecutor:
       ``n_slots`` — returning the logits in ``slot_idx`` order: one
       compilation per pool.
 
+    Both return ``(logits, picks, pool)``: ``picks`` holds each row's
+    greedy token per codebook and its all-finite flag, ``(B, K + 1)``
+    int32, taken inside the program from the logits it returns.
+    :meth:`sample` and :meth:`row_finite` answer for the last step's
+    logits from one host copy of them; ``picks_fused`` counts those
+    calls and ``picks_eager`` the calls on any other logits.
+
     Both donate the pool, so the slot pool is updated in place
     buffer-wise.  The FAµST dispatch staged while tracing is captured
     (same mark technique as the old ``Server``) on ``faust_dispatch``;
@@ -399,7 +412,25 @@ class LMExecutor:
         self._faust_op = self._build_faust_op()
         self._dispatch_memo: dict = {}  # live batch -> advisory report
 
+        # the last step's logits object, its program's picks, and their
+        # host copy once made: (logits, picks, host or None)
+        self._step_picks = None
+        self.picks_fused = 0  # sample/row_finite calls read from the program's picks
+        self.picks_eager = 0  # sample/row_finite calls reduced eagerly (other logits)
+
         dtype = self._act_dtype
+
+        def _with_picks(logits):
+            """``logits`` and the picks taken from the very array returned:
+            each row's greedy token per codebook and its all-finite flag as
+            one more column, ``(B, K + 1)`` int32 — the expressions of
+            :meth:`sample` and :meth:`row_finite`, so they agree bit for bit
+            (the barrier keeps the argmax off any unrounded producer)."""
+            logits = jax.lax.optimization_barrier(logits)
+            step = logits[:, -1]  # (B, V) or (B, K, V)
+            tok = jnp.argmax(step, axis=-1).astype(jnp.int32).reshape(step.shape[0], -1)
+            fin = jnp.isfinite(step.astype(jnp.float32)).reshape(step.shape[0], -1).all(axis=-1)
+            return logits, jnp.concatenate([tok, fin[:, None].astype(jnp.int32)], axis=1)
 
         def _prefill(params, batch, pool, slot):
             with shd.use_rules(mesh, cfg.decode_policy()):
@@ -412,13 +443,14 @@ class LMExecutor:
                     pool,
                     caches,
                 )
-                return logits, pool
+                return *_with_picks(logits), pool
 
         def _decode(params, tokens, pool, slot_idx):
             with shd.use_rules(mesh, cfg.decode_policy()):
                 logits, update = lm.decode_delta(params, cfg, tokens, pool)
                 pool = lm.scatter_cache_slots(pool, update, slot_idx)
-                return jnp.take(logits, slot_idx, axis=0, mode="clip"), pool
+                logits = jnp.take(logits, slot_idx, axis=0, mode="clip")
+                return *_with_picks(logits), pool
 
         self._prefill_fn = jax.jit(_prefill, donate_argnums=2)
         self._decode_fn = jax.jit(_decode, donate_argnums=2)
@@ -531,9 +563,10 @@ class LMExecutor:
             for k, v in extras.items():
                 batch[k] = jnp.asarray(v)[None]
             mark = _dispatch.last_report()
-            logits, self.pool = self._prefill_fn(
+            logits, picks, self.pool = self._prefill_fn(
                 self.params, batch, self.pool, jnp.asarray(slot, jnp.int32)
             )
+            self._step_picks = (logits, picks, None)
             for i in range(tail.shape[-1]):
                 tok = tail[..., i : i + 1][None]  # (1,1)/(1,K,1)
                 logits = self._decode_pool([slot], tok)
@@ -556,10 +589,13 @@ class LMExecutor:
         pool_tokens[slots] = tokens  # free slots decode token 0, unwritten
         slot_idx = np.full(n, n, np.int32)  # padding past the pool is dropped
         slot_idx[:b] = slots
-        logits, self.pool = self._decode_fn(
+        logits, picks, self.pool = self._decode_fn(
             self.params, jnp.asarray(pool_tokens), self.pool, jnp.asarray(slot_idx)
         )
-        return logits if b == n else logits[:b]
+        if b < n:
+            logits = logits[:b]
+        self._step_picks = (logits, picks, None)
+        return logits
 
     def _decode_forward(self, slots, tokens):
         from repro.api import dispatch as _dispatch
@@ -574,23 +610,52 @@ class LMExecutor:
             self.faust_dispatch = _dispatch.last_report()
         return logits
 
+    def _fused(self, logits) -> bool:
+        """Whether ``logits`` is the object the last step returned, whose
+        program picked its tokens and flags.  Anything else — the
+        ``FaultInjector``'s poisoned copy, a slice, another executor's
+        logits — is reduced eagerly."""
+        return self._step_picks is not None and logits is self._step_picks[0]
+
+    def _host_picks(self, logits) -> np.ndarray:
+        """The last step's picks, ``(B, K + 1)``, copied to the host on
+        first use: the step's one readback."""
+        held, picks, host = self._step_picks
+        if host is None:
+            host = np.asarray(picks)[: logits.shape[0]]
+            self._step_picks = (held, picks, host)
+        return host
+
     def sample(self, logits) -> np.ndarray:
         """Greedy argmax of the last position — same slicing contract as
         ``Server._sample`` (seq axis is axis 1 in both logits layouts)."""
         jnp = self._jnp
-        with _span("executor.sample"):
-            step = logits[:, -1]  # (B, V) or (B, K, V)
-            tok = jnp.argmax(step, axis=-1).astype(jnp.int32)
+        fused = self._fused(logits)
+        with _span("executor.sample", "fused", int(fused)):
+            if fused:
+                self.picks_fused += 1
+                tok = self._host_picks(logits)[:, :-1]  # (B, K)
+            else:
+                self.picks_eager += 1
+                step = logits[:, -1]  # (B, V) or (B, K, V)
+                tok = jnp.argmax(step, axis=-1).astype(jnp.int32)
             if self.cfg.n_codebooks > 1:
                 return np.asarray(tok.reshape(tok.shape[0], self.cfg.n_codebooks, 1))
             return np.asarray(tok.reshape(-1, 1))
 
     def row_finite(self, logits) -> np.ndarray:
         """Per-row all-finite mask of the last position, ``(B,)`` bool —
-        the engine's NaN guard.  Reduced on device so the guard moves B
-        bools per step instead of the ``(B, V)`` logits."""
+        the engine's NaN guard.  The last step's program reduced it
+        already (read from :meth:`sample`'s host copy); other logits are
+        reduced on device, so the guard moves B bools instead of the
+        ``(B, V)`` logits."""
         jnp = self._jnp
-        with _span("executor.row_finite"):
+        fused = self._fused(logits)
+        with _span("executor.row_finite", "fused", int(fused)):
+            if fused:
+                self.picks_fused += 1
+                return self._host_picks(logits)[:, -1].astype(bool)
+            self.picks_eager += 1
             step = logits[:, -1].astype(jnp.float32)  # (B, V) or (B, K, V)
             fin = jnp.isfinite(step).reshape(step.shape[0], -1).all(axis=-1)
             return np.asarray(fin)
@@ -629,7 +694,7 @@ class Engine:
     * ``default_ttl`` — deadline applied to every submit that does not
       pass its own ``ttl`` (default none).
     * ``nan_guard`` — per-stream quarantine of non-finite logits rows
-      (default on; costs one finiteness reduction per step).
+      (default on; ``LMExecutor`` reduces it inside the step program).
     * ``sleep`` — how the engine waits out retry backoff when nothing is
       live (default: ``clock.advance`` when the clock has one — the sim
       FakeClock — else ``time.sleep``).

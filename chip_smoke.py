@@ -420,9 +420,6 @@ def main(argv=None) -> int:
     if len(devices) < args.chips:
         print(f"chip_smoke: --chips {args.chips} but {len(devices)} device(s)", file=sys.stderr)
         return 2
-    # no host state from outside the checkout: model-priced dispatch only
-    os.environ["REPRO_AUTOTUNE"] = "off"
-    os.environ["REPRO_ROOFLINE"] = "builtin"
     sys.path.insert(0, os.path.join(HERE, "src"))
     from repro.launch.compile_cache import use_compile_cache
 

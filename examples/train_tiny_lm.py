@@ -3,9 +3,8 @@ hundred steps on the synthetic pipeline, with checkpoint/resume and an
 optional FAµST-parameterized unembedding + FFN — the paper's technique as a
 *training-time* parameterization (prescribed-support constraint sets).
 
-On-CPU-container note: the model is a reduced same-family config (full
-configs are exercised by the dry-run); on a real pod this script is the
-same entry point with --mesh.
+On a CPU the model is a reduced same-family config; on a real pod this
+script is the same entry point with --mesh.
 
 Run: PYTHONPATH=src:. python examples/train_tiny_lm.py [--faust] [--steps 200]
 """

@@ -32,11 +32,7 @@ if ! python scripts/check_docs.py; then
     exit 1
 fi
 
-# REPRO_AUTOTUNE=off on the tier-1 and bench legs: decisions must stay
-# host-independent, model-priced (any autotune table this host has built
-# would otherwise steer backend="auto" assertions and BENCH rows).
-out=$(PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} REPRO_AUTOTUNE=off \
-      timeout "$CI_TIMEOUT" \
+out=$(PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} timeout "$CI_TIMEOUT" \
       python -m pytest -q tests 2>&1)
 status=$?
 echo "$out" | tail -20
@@ -67,7 +63,7 @@ fi
 # override so they execute on every push, not just when a TPU is attached.
 # The engine-sim suite rides along: the scheduler traces re-run here with
 # 8 host devices, which unlocks the engine-vs-Server multi-device parity
-# case (autotune stays pinned off via tests/conftest.py either way).
+# case.
 mdout=$(XLA_FLAGS="--xla_force_host_platform_device_count=8${XLA_FLAGS:+ $XLA_FLAGS}" \
         PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} timeout "$CI_TIMEOUT" \
         python -m pytest -q tests/test_sharded_apply.py tests/test_sharding.py \
@@ -86,11 +82,9 @@ echo "ci: multi-device leg OK"
 # Perf-regression leg: re-run the cheap apply benchmarks and gate >25%
 # relative regressions against the committed BENCH_baseline.json
 # (scripts/check_bench.py).  REPRO_SKIP_BENCH=1 skips it on slow/noisy
-# hosts; REPRO_ROOFLINE=builtin pins the dispatch constants so a host
-# calibration cache can't shift which backend the rows measure.
+# hosts.
 if [ "${REPRO_SKIP_BENCH:-0}" != "1" ]; then
-    if ! PYTHONPATH=src:.${PYTHONPATH:+:$PYTHONPATH} REPRO_ROOFLINE=builtin \
-        REPRO_AUTOTUNE=off timeout "$CI_TIMEOUT" \
+    if ! PYTHONPATH=src:.${PYTHONPATH:+:$PYTHONPATH} timeout "$CI_TIMEOUT" \
         python benchmarks/run.py --only apply_speed,apply_grad,serve_load \
         --json /tmp/repro_bench_ci.json > /dev/null; then
         echo "ci: BENCH LEG FAILED TO RUN"
@@ -104,8 +98,8 @@ if [ "${REPRO_SKIP_BENCH:-0}" != "1" ]; then
     # warm-vs-cold tracking pipeline end to end; the sweep-budget claim
     # itself is asserted in tests/test_streaming.py, the wall-µs rows are
     # gated (informationally, sub-100µs rows excepted) like the rest.
-    if ! PYTHONPATH=src:.${PYTHONPATH:+:$PYTHONPATH} REPRO_ROOFLINE=builtin \
-        REPRO_AUTOTUNE=off REPRO_STREAM_SMOKE=1 timeout "$CI_TIMEOUT" \
+    if ! PYTHONPATH=src:.${PYTHONPATH:+:$PYTHONPATH} REPRO_STREAM_SMOKE=1 \
+        timeout "$CI_TIMEOUT" \
         python benchmarks/run.py --only streaming_track \
         --json /tmp/repro_bench_stream.json > /dev/null; then
         echo "ci: STREAMING BENCH SMOKE FAILED TO RUN"
@@ -120,72 +114,11 @@ else
     echo "ci: bench leg skipped (REPRO_SKIP_BENCH=1)"
 fi
 
-# Autotune smoke leg: build a measured table on 2 tiny shapes, assert a
-# dispatch table hit (source == "measured"), then corrupt the file and
-# assert the model fallback — the full mechanics are unit-tested in
-# tests/test_autotune.py; this leg proves the CLI workflow end to end.
-at_table=$(mktemp /tmp/repro_autotune_ci.XXXXXX.json)
-rm -f "$at_table"
-if ! PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} REPRO_ROOFLINE=builtin \
-    REPRO_AUTOTUNE_TABLE="$at_table" REPRO_AUTOTUNE_ITERS=1,2 \
-    REPRO_AUTOTUNE_BT=8,16 timeout "$CI_TIMEOUT" \
-    python scripts/calibrate_roofline.py --autotune --no-grad --batch 16 \
-    --cases "32,32,2,2,8;32,64,2,2,8" > /dev/null; then
-    echo "ci: AUTOTUNE SMOKE (table build) FAILED"
-    rm -f "$at_table"
-    exit 1
-fi
-if ! PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} REPRO_ROOFLINE=builtin \
-    REPRO_AUTOTUNE_TABLE="$at_table" timeout "$CI_TIMEOUT" \
-    python - <<'EOF'
-import json, os, sys
-import jax, jax.numpy as jnp
-from repro.api import FaustOp, dispatch, autotune
-from repro.core.compress import BlockFaust, random_block_factor
-
-def op_for(m, n):
-    ks = jax.random.split(jax.random.PRNGKey(0), 2)
-    dims = [m, min(m, n), n]
-    return FaustOp.wrap(BlockFaust(tuple(
-        random_block_factor(ks[i], dims[i], dims[i + 1], 8, 8, 2)
-        for i in range(2)), jnp.asarray(1.0)))
-
-table = json.load(open(os.environ["REPRO_AUTOTUNE_TABLE"]))
-assert table["version"] == autotune.TABLE_VERSION
-assert len(table["entries"]) == 2, table["entries"].keys()
-for m, n in ((32, 32), (32, 64)):
-    rep = dispatch.dispatch(op_for(m, n), 16, jnp.float32)
-    assert rep.source == "measured", (m, n, rep.source, rep.reason)
-    assert rep.backend == min(rep.est_us, key=rep.est_us.get)
-# corrupt the table: dispatch must fall back to the model, not raise
-with open(os.environ["REPRO_AUTOTUNE_TABLE"], "w") as f:
-    f.write("{corrupt")
-autotune.reload()
-rep = dispatch.dispatch(op_for(32, 32), 16, jnp.float32)
-assert rep.source == "model", rep.source
-# stale version: same fallback
-json.dump({"version": autotune.TABLE_VERSION + 1, "entries": {}},
-          open(os.environ["REPRO_AUTOTUNE_TABLE"], "w"))
-autotune.reload()
-rep = dispatch.dispatch(op_for(32, 32), 16, jnp.float32)
-assert rep.source == "model", rep.source
-print("autotune smoke: measured hits + corrupt/stale fallback OK")
-EOF
-then
-    echo "ci: AUTOTUNE SMOKE (dispatch assertions) FAILED"
-    rm -f "$at_table"
-    exit 1
-fi
-rm -f "$at_table"
-echo "ci: autotune smoke leg OK"
-
 # Quantization smoke leg: quantize a small chain, assert fwd/bwd parity
 # vs the dequantized-f32 apply and that dispatch prices the reduced byte
 # term — the full matrix is tests/test_quantized_chain.py; this leg
-# proves the quantize → apply → grad → dispatch workflow end to end
-# under the same pinned-model environment as the other legs.
-if ! PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} REPRO_ROOFLINE=builtin \
-    REPRO_AUTOTUNE=off timeout "$CI_TIMEOUT" \
+# proves the quantize → apply → grad → dispatch workflow end to end.
+if ! PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} timeout "$CI_TIMEOUT" \
     python - <<'EOF'
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
@@ -241,14 +174,12 @@ echo "ci: quantization smoke leg OK"
 # at ~10% decode fault rate must keep nonzero goodput while shedding,
 # retrying, and quarantining (benchmarks/serve_load.py --faults asserts
 # goodput > 0, retries > 0, failed == quarantined == 1, shed == 2).
-if ! PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} REPRO_AUTOTUNE=off \
-    timeout "$CI_TIMEOUT" \
+if ! PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} timeout "$CI_TIMEOUT" \
     python -m pytest -q tests/test_engine_faults.py > /dev/null; then
     echo "ci: FAULT SUITE FAILED"
     exit 1
 fi
-if ! PYTHONPATH=src:.${PYTHONPATH:+:$PYTHONPATH} REPRO_ROOFLINE=builtin \
-    REPRO_AUTOTUNE=off timeout "$CI_TIMEOUT" \
+if ! PYTHONPATH=src:.${PYTHONPATH:+:$PYTHONPATH} timeout "$CI_TIMEOUT" \
     python benchmarks/serve_load.py --faults > /dev/null; then
     echo "ci: FAULT-INJECTION SMOKE FAILED"
     exit 1

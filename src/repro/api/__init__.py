@@ -1,7 +1,5 @@
 # The unified operator layer: one operator object (FaustOp), one
-# factorization front door (factorize), cost-model backend dispatch
-# (with the measured autotune layer on top — repro.api.autotune).
-from repro.api import autotune
+# factorization front door (factorize), cost-model backend dispatch.
 from repro.api.dispatch import (
     DispatchReport,
     choose_backend,
@@ -22,7 +20,6 @@ from repro.api.operator import (
 
 __all__ = [
     "DispatchReport",
-    "autotune",
     "FactorizeInfo",
     "FactorizeSpec",
     "FaustOp",

@@ -2,10 +2,9 @@
 
 ``apply(x, backend="auto")`` has three concrete execution paths (dense
 matmul, per-factor BSR chain, fused packed chain) whose crossover depends
-on (batch, shape, dtype, device).  This module picks among them with the
-same roofline machinery the launch tooling uses
-(``launch/roofline.py`` peak constants; ``launch/hlo_cost.py`` for the
-compiled ground truth):
+on (batch, shape, dtype, device).  This module picks among them with a
+roofline model priced at the builtin constants of the chip's
+``device_kind`` (:func:`builtin_constants`):
 
     t(backend) ≈ max(flops / PEAK_FLOPS, bytes / HBM_BW) + launches·t_launch
 
@@ -41,20 +40,12 @@ walk, 1 launch, one ``s_tot`` f32 cotangent store per batch tile).  A
 Every decision is materialized as a :class:`DispatchReport` — benchmarks
 record it next to their numbers (``benchmarks/run.py --json``) and tests
 assert which path ran (the report is also retrievable after the fact via
-:func:`last_report`).  The model is the *TPU* roofline by default even
-off-TPU — the decision is then a pure function of (batch, shape, dtype),
-not of where the benchmark happened to run — unless the operator has
-opted in to host-measured constants via
-``scripts/calibrate_roofline.py`` (the report's ``roofline`` field names
-the source either way).
-
-Above the model sits the **measured autotuner**
-(:mod:`repro.api.autotune`): where a real timing exists in the autotune
-table, an ``auto`` dispatch stops trusting the closed form — the
-decision is the measured-fastest feasible backend, the report's
-``source`` is ``"measured"`` and ``est_us`` hold real µs.
-``REPRO_AUTOTUNE=off`` disables the table entirely, reproducing pure
-model-priced decisions bit-for-bit (what CI pins).
+:func:`last_report`).  The model is the *TPU* roofline even off-TPU, and
+no file or environment variable steers it, so the backend and the batch
+tile are a pure function of (operator, batch, dtype, grad, shard).  The
+one exception is degraded mode (``REPRO_DEGRADED``, opt-in; see
+``FaustOp.apply``): a backend that raised is quarantined for the session
+(:func:`quarantine_backend`) and later ``auto`` decisions skip it.
 """
 from __future__ import annotations
 
@@ -64,7 +55,39 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.chain import DEFAULT_BT
-from repro.launch.roofline import roofline_constants
+
+# Builtin per-chip constants, keyed by ``jax.Device.device_kind``.  Peaks
+# from the Google Cloud TPU documentation ("TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM); ``link_bw`` and ``t_launch_us`` are model constants that
+# no chip run has measured yet.
+_BUILTIN_BY_KIND = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,  # bf16 / chip
+        "hbm_bw": 819e9,  # bytes/s / chip
+        "link_bw": 50e9,  # bytes/s / link (ICI)
+        "t_launch_us": 2.0,  # fixed per-launch overhead (µs)
+    },
+}
+# Off-TPU (CPU tests, compile rehearsals) dispatch prices the chip the
+# repo deploys on, so its decisions stay a pure function of the shapes.
+_REFERENCE_KIND = "TPU v5 lite"
+_BUILTIN = _BUILTIN_BY_KIND[_REFERENCE_KIND]
+
+
+def builtin_constants() -> dict:
+    """Builtin constants for the default device: its ``device_kind`` entry
+    on a TPU — a TPU kind missing from the table raises rather than being
+    priced as another chip — and the reference chip's off-TPU."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return dict(_BUILTIN)
+    if dev.device_kind not in _BUILTIN_BY_KIND:
+        raise KeyError(
+            f"no builtin roofline constants for TPU kind {dev.device_kind!r}; "
+            f"known kinds: {sorted(_BUILTIN_BY_KIND)}"
+        )
+    return dict(_BUILTIN_BY_KIND[dev.device_kind])
+
 
 # Stable preference on est_us ties: fewest-launch structured path first
 # (single-device fused before sharded — a tie means the mesh buys nothing).
@@ -76,8 +99,8 @@ def _wgrad_spill_bytes(b: int, s_tot: float, bt: int = DEFAULT_BT) -> float:
     wider than one tile store (and re-read for the sum) one ``s_tot`` f32
     slab per *extra* tile — single-tile batches write dvalues exactly
     once, already counted in the weight-stream term.  ``bt`` is the batch
-    tile the wgrad kernel will actually run at (caller-forced or
-    autotuned; ``kernels/chain_bwd.py`` default otherwise) — smaller
+    tile the wgrad kernel will actually run at (caller-forced, else
+    ``kernels/chain_bwd.py``'s default, fitted to VMEM) — smaller
     tiles mean more spill slabs, so the grad pricing must see the real
     one.  Shared by the single-device and per-shard grad pricings."""
     return 8.0 * s_tot * (max(-(-b // max(bt, 1)), 1) - 1)
@@ -102,17 +125,12 @@ class DispatchReport:
     collective_bytes: int = 0  # per-shard ICI bytes of the sharded plan
     # training-aware pricing: True ⇔ est_us are joint forward+backward costs
     grad: bool = False
-    # which roofline constants priced this decision ("builtin" or the
-    # calibration cache path — see launch/roofline.py; read live via
-    # roofline_constants(), so a mid-process calibration shows up here)
-    roofline: str = "builtin"
-    # where est_us came from: "model" (analytic roofline) or "measured"
-    # (autotune table hit — est_us are then real host µs and `backend` is
-    # the measured-fastest feasible path; see repro.api.autotune)
+    # how the backend was decided: "model" (the roofline above) or
+    # "demoted" (degraded mode re-priced after the model's pick raised)
     source: str = "model"
     # the chain kernels' batch tile this decision priced and the apply
-    # runs: the requested tile (caller-forced > autotuned winner >
-    # DEFAULT_BT) halved until the kernels' VMEM footprint fits
+    # runs: the requested tile (caller-forced, else DEFAULT_BT) halved
+    # until the kernels' VMEM footprint fits
     bt: int = DEFAULT_BT
     # the weight-stream bytes the structured backends were priced at:
     # values bytes (post-quantization — 1 byte/value for int8/fp8 payloads)
@@ -123,8 +141,8 @@ class DispatchReport:
     values_dtype: str = ""
     # degraded-mode dispatch: the backend that raised at apply time and
     # was replaced by ``backend`` (source is then "demoted"; the failing
-    # (signature, backend) pair is session-quarantined in the autotune
-    # layer so later auto dispatches skip it up front)
+    # (signature, backend) pair is session-quarantined so later auto
+    # dispatches skip it up front)
     demoted_from: str | None = None
 
     def as_row(self) -> dict:
@@ -140,7 +158,6 @@ class DispatchReport:
             "est_us": {k: round(v, 3) for k, v in self.est_us.items()},
             "reason": self.reason,
             "grad": self.grad,
-            "roofline": self.roofline,
             "source": self.source,
             "bt": self.bt,
             "weight_bytes": self.weight_bytes,
@@ -202,15 +219,11 @@ def choose_backend(
     with per-shard roofline terms plus the ICI collective term.
     ``grad=True`` prices forward+backward jointly (see module docstring);
     ``bt`` is the chain kernels' batch tile the apply will run at — it
-    prices the wgrad partial-dvalues spill, so a caller-forced (or
-    autotuned) tile changes the grad estimates.
-
-    Roofline constants are read through the live accessor
-    (:func:`repro.launch.roofline.roofline_constants`) — a calibration
-    written after import, or a ``REPRO_ROOFLINE`` flip, reprices the very
-    next decision and ``DispatchReport.roofline`` names the real source.
+    prices the wgrad partial-dvalues spill, so a caller-forced tile
+    changes the grad estimates.  The constants are
+    :func:`builtin_constants`.
     """
-    consts, roofline_src = roofline_constants()
+    consts = builtin_constants()
     peak_flops, hbm_bw = consts["peak_flops"], consts["hbm_bw"]
     link_bw, launch_us = consts["link_bw"], consts["t_launch_us"]
     m, n = shape
@@ -334,7 +347,6 @@ def choose_backend(
         mesh_shape=shard.get("mesh_shape") if shard is not None else None,
         collective_bytes=coll_bytes,
         grad=grad,
-        roofline=roofline_src,
         bt=bt,
         weight_bytes=weight_bytes,
         values_dtype=(
@@ -430,6 +442,37 @@ def _sharded_est(
     return roofline_us(flops, byts, launches, coll_est), coll_bytes
 
 
+# (operator signature, backend) pairs that raised at apply time this
+# session (degraded mode).  Process-local and never persisted: a launch
+# failure is a property of this host and session (driver state, VMEM
+# pressure, a broken lowering), not of the operator — the next process
+# tries the full ladder again.
+_QUARANTINE: set[tuple[str, str]] = set()
+
+
+def _signature(op) -> str:
+    """What a quarantine is keyed by: shape, chain length and stored
+    nonzeros, shared by every batch, dtype and mesh of one operator."""
+    return f"{op.shape[0]}x{op.shape[1]}|J{op.n_factors}|s{op.s_tot}|"
+
+
+def quarantine_backend(op, backend: str) -> None:
+    """Bar ``backend`` from auto dispatch for every operator sharing
+    ``op``'s signature for this process."""
+    _QUARANTINE.add((_signature(op), backend))
+
+
+def quarantined_backends(op) -> frozenset[str]:
+    """Backends quarantined for ``op``'s signature this session."""
+    sig = _signature(op)
+    return frozenset(b for s, b in _QUARANTINE if s == sig)
+
+
+def clear_quarantine() -> None:
+    """Reset the session quarantine (tests)."""
+    _QUARANTINE.clear()
+
+
 def dispatch(
     op, batch: int, dtype, requested: str = "auto", shard: dict | None = None,
     grad: bool = False, bt: int | None = None, record: bool = True,
@@ -444,19 +487,12 @@ def dispatch(
     :meth:`~repro.kernels.chain_sharded.ShardPlan.summary` when it carries
     a ShardSpec; ``grad=True`` prices forward+backward jointly (set by
     ``FaustOp.apply`` when it detects an AD trace).  ``bt`` is the
-    caller-forced chain batch tile, or None to let the decision pick
-    (autotuned winner on a table hit, ``DEFAULT_BT`` otherwise) — the
-    resolved tile comes back on ``DispatchReport.bt`` and
-    ``FaustOp.apply`` runs the chain kernels at it.
-
-    Autotune (``repro.api.autotune``): unless ``REPRO_AUTOTUNE=off``, an
-    ``auto`` request first consults the measured-timings table.  On a hit
-    the decision is the measured-fastest backend *among this leaf's
-    feasible set*, ``est_us`` are the real host µs, and ``source`` flips
-    to ``"measured"`` — model and measured numbers are never mixed in one
-    comparison.  Misses (and every forced request) price with the model
-    exactly as before.  Composite operators dispatch per leaf during
-    ``apply``; :func:`last_report` returns the latest decision either way.
+    caller-forced chain batch tile, or None for ``DEFAULT_BT``; either is
+    fitted to the kernels' VMEM budget (:func:`fit_chain_bt`), and the
+    resolved tile comes back on ``DispatchReport.bt`` —
+    ``FaustOp.apply`` runs the chain kernels at it.  Composite operators
+    dispatch per leaf during ``apply``; :func:`last_report` returns the
+    latest decision either way.
 
     ``record=False`` makes the call a pure *query*: the report is
     computed identically but :func:`last_report` is left untouched, so an
@@ -468,36 +504,19 @@ def dispatch(
     feasible backends) — the degraded-mode re-dispatch in
     ``FaustOp.apply`` uses it to re-price after a backend raised.  Auto
     requests additionally skip backends session-quarantined for this
-    operator's signature (``autotune.quarantine_backend``), unless that
-    would leave nothing.
+    operator's signature (:func:`quarantine_backend`), unless that would
+    leave nothing.
     """
-    from repro.api import autotune as _autotune
-
     cand = op.feasible_backends() if feasible is None else tuple(feasible)
-    if requested == "auto" and _autotune._QUARANTINE:
-        barred = _autotune.quarantined_backends(_autotune.op_key_prefix(op))
+    if requested == "auto" and _QUARANTINE:
+        barred = quarantined_backends(op)
         kept = tuple(b for b in cand if b not in barred)
         if kept:
             cand = kept
-    entry = None
-    if requested == "auto" and _autotune.autotune_mode() != "off":
-        # key_for_op is the one shared spelling of the lookup key — the
-        # measurement layer and the hot-swap invalidator build the same
-        # string, so a values-only swap keeps hitting and an invalidated
-        # signature reliably misses.
-        key = _autotune.key_for_op(
-            op,
-            batch=batch,
-            dtype=dtype,
-            grad=grad,
-            mesh_shape=shard.get("mesh_shape") if shard is not None else None,
-        )
-        entry = _autotune.lookup(key)
-    eff_bt = bt if bt is not None else (
-        int(entry["bt"]) if entry is not None and entry.get("bt") else DEFAULT_BT
-    )
     values_dtype, scales_bytes = op.quant_info()
-    eff_bt, unfit = fit_chain_bt(op, eff_bt, dtype, values_dtype, grad)
+    eff_bt, unfit = fit_chain_bt(
+        op, DEFAULT_BT if bt is None else bt, dtype, values_dtype, grad
+    )
     if "fused" not in cand:
         unfit = None
     if unfit is not None and requested != "fused":
@@ -517,35 +536,6 @@ def dispatch(
         values_dtype=values_dtype,
         scales_bytes=scales_bytes,
     )
-    if entry is not None:
-        measured = {
-            k: float(v)
-            for k, v in entry["us"].items()
-            if k in report.feasible and isinstance(v, (int, float))
-        }
-        if measured:
-            backend = min(measured, key=lambda k: (measured[k], _ORDER.get(k, 9)))
-            runner = min(
-                (k for k in measured if k != backend),
-                key=lambda k: (measured[k], _ORDER.get(k, 9)),
-                default=None,
-            )
-            vs = (
-                f" vs {runner} {measured[runner]:.2f}us" if runner else ""
-            )
-            report = dataclasses.replace(
-                report,
-                backend=backend,
-                est_us=measured,
-                feasible=tuple(measured),
-                source="measured",
-                reason=(
-                    f"measured table hit: {backend} "
-                    f"{measured[backend]:.2f}us{vs} "
-                    f"(model would pick {report.backend}; "
-                    f"weight_bytes={report.weight_bytes})"
-                ),
-            )
     if unfit is not None:
         report = dataclasses.replace(
             report, reason=f"{report.reason}; fused ruled out: {unfit}"

@@ -410,7 +410,6 @@ class FaustOp:
         bt: int | None = None,
         interpret: bool | None = None,
         grad: bool | None = None,
-        autotune: bool | None = None,
     ) -> Array:
         """``y = x @ todense()`` for ``x (..., shape[0])`` — the paper's
         O(s_tot) multiplication, on the backend of your choice:
@@ -442,14 +441,9 @@ class FaustOp:
         trace from detection — see :func:`_under_ad` — so pass
         ``grad=True`` there).
 
-        ``bt=None`` lets dispatch choose the chain kernels' batch tile
-        (the autotuned winner on a table hit, the kernels' default
-        otherwise); an explicit ``bt`` always wins.  ``autotune=None``
-        follows ``REPRO_AUTOTUNE`` (``1`` ⇒ measure unseen keys on
-        eager applies); ``autotune=True`` forces measurement for this
-        apply, ``False`` suppresses it — either way existing table hits
-        still steer ``backend="auto"`` unless ``REPRO_AUTOTUNE=off``
-        (see :mod:`repro.api.autotune`).
+        ``bt=None`` runs the chain kernels at their default batch tile;
+        an explicit ``bt`` wins.  Either is fitted to the kernels' VMEM
+        budget by dispatch (:func:`repro.api.dispatch.fit_chain_bt`).
         """
         if backend not in BACKENDS:
             raise ValueError(f"backend must be one of {BACKENDS}; got {backend!r}")
@@ -459,39 +453,31 @@ class FaustOp:
             interpret = jax.default_backend() != "tpu"
         if grad is None:
             grad = _under_ad(x, self)  # FaustOp is a pytree: covers all leaves
-        if autotune is None:
-            from repro.api import autotune as _at
-
-            autotune = _at.autotune_mode() == "measure"
         if x.shape[-1] != self.shape[0]:
             raise ValueError(
                 f"apply expects x (..., {self.shape[0]}); got {x.shape}"
             )
-        return self._apply(x, backend, use_kernel, bt, interpret, grad, autotune)
+        return self._apply(x, backend, use_kernel, bt, interpret, grad)
 
-    def _apply(
-        self, x, backend, use_kernel, bt, interpret, grad=False, autotune=False
-    ) -> Array:
+    def _apply(self, x, backend, use_kernel, bt, interpret, grad=False) -> Array:
         if self.kind == "leaf":
-            return self._leaf_apply(
-                x, backend, use_kernel, bt, interpret, grad, autotune
-            )
+            return self._leaf_apply(x, backend, use_kernel, bt, interpret, grad)
         if self.kind == "compose":
             y = x
             for c in self.children:
-                y = c._apply(y, backend, use_kernel, bt, interpret, grad, autotune)
+                y = c._apply(y, backend, use_kernel, bt, interpret, grad)
             return y
         ms = [c.shape[0] for c in self.children]
         if self.kind == "hstack":
             return jnp.concatenate(
-                [c._apply(x, backend, use_kernel, bt, interpret, grad, autotune)
+                [c._apply(x, backend, use_kernel, bt, interpret, grad)
                  for c in self.children],
                 axis=-1,
             )
         splits = np.cumsum(ms[:-1]).tolist()
         parts = jnp.split(x, splits, axis=-1)
         ys = [
-            c._apply(p, backend, use_kernel, bt, interpret, grad, autotune)
+            c._apply(p, backend, use_kernel, bt, interpret, grad)
             for c, p in zip(self.children, parts)
         ]
         if self.kind == "vstack":
@@ -499,7 +485,7 @@ class FaustOp:
         return jnp.concatenate(ys, axis=-1)  # block_diag
 
     def _leaf_apply(
-        self, x, backend, use_kernel, bt, interpret, grad=False, autotune=False
+        self, x, backend, use_kernel, bt, interpret, grad=False
     ) -> Array:
         from repro.api import dispatch as _dispatch
 
@@ -527,20 +513,6 @@ class FaustOp:
                 self.shard.data_axis, self.shard.model_axis,
             )
         shard_summary = shard_plan.summary() if shard_plan is not None else None
-        if autotune and backend == "auto":
-            # Measure-and-persist this key before deciding, so the very
-            # dispatch below can hit the fresh entry.  No-op inside a
-            # trace or re-entrantly from a measurement apply.
-            from repro.api import autotune as _at
-
-            _at.ensure_measured(
-                self, x,
-                batch=batch_of(x), dtype=x.dtype, grad=grad,
-                mesh_shape=(
-                    shard_summary.get("mesh_shape") if shard_summary else None
-                ),
-                use_kernel=use_kernel, interpret=interpret,
-            )
         # auto and forced decisions both land on dispatch.last_report()
         requested = backend
         report = _dispatch.dispatch(
@@ -569,9 +541,7 @@ class FaustOp:
             )
             if requested != "auto" or not _degraded_on() or not ladder:
                 raise
-            from repro.api import autotune as _at
-
-            _at.quarantine_backend(_at.op_key_prefix(self), report.backend)
+            _dispatch.quarantine_backend(self, report.backend)
             demoted = _dispatch.dispatch(
                 self, batch_of(x), x.dtype, requested="auto",
                 shard=shard_summary, grad=grad, bt=None, record=False,
@@ -708,9 +678,8 @@ class FaustOp:
         anything and without touching :func:`repro.api.dispatch.last_report`
         (``record=False``).  The serving engine calls this every decode
         step with the *live* batch size so the chosen backend (and ``bt``
-        tile) follows the batch as it breathes; the same autotune-table /
-        roofline-model machinery prices the answer, so ``source`` tells
-        whether a measurement or the closed form decided.  Composites
+        tile) follows the batch as it breathes; the same roofline model
+        prices the answer.  Composites
         return the last leaf's report (leaves dispatch independently
         during a real ``apply``)."""
         if self.kind != "leaf":

@@ -13,7 +13,7 @@ from repro.configs.base import ArchConfig, CP_POLICY, DECODE_POLICY
 from repro.distributed.sharding import ShardingPolicy
 from repro.layers.moe import MoESpec
 
-# CP activations + gather-at-MoE-boundary (ff-TP experts; §Perf iter. 4)
+# CP activations + gather-at-MoE-boundary (ff-TP experts)
 GRANITE_POLICY = ShardingPolicy(seq="model", heads_act=None, moe_gather_seq=True)
 
 CONFIG = ArchConfig(

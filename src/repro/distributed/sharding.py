@@ -61,8 +61,8 @@ class ShardingPolicy:
     vocab_act: MeshAxes = "model"
     experts_act: MeshAxes = "model"
     # gather the sequence dim at the MoE boundary (helps ff-TP experts whose
-    # routing conflicts with context-parallel seq sharding; hurts EP experts
-    # — see EXPERIMENTS.md §Perf iteration 4)
+    # routing conflicts with context-parallel seq sharding; hurts EP
+    # experts, whose all-to-all already moves exactly the routed tokens)
     moe_gather_seq: bool = False
     # parameters (logical param axes from repro.layers.param)
     params: dict[str, MeshAxes] = dataclasses.field(

@@ -80,11 +80,9 @@ Array = jax.Array
 META_COLS = 7
 
 # Default batch-tile rows per kernel invocation.  Single-sourced here so
-# the apply wrappers, the dispatch wgrad-spill pricing and the autotuner's
-# tile sweep (``repro.api.autotune``) all agree on what "default" means;
-# the autotuner may persist a different winner per shape and
-# ``FaustOp.apply`` then runs the chain kernels at the tuned tile unless
-# the caller forces ``bt=``.
+# the apply wrappers and the dispatch pricing agree on what "default"
+# means; ``FaustOp.apply`` runs the chain kernels at it (fitted to VMEM by
+# ``repro.api.dispatch.fit_chain_bt``) unless the caller forces ``bt=``.
 DEFAULT_BT = 128
 MIN_BT = 8  # sublane tile: the smallest batch tile Mosaic lays out
 

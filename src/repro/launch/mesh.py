@@ -4,9 +4,7 @@ single-pod: (16, 16) over ("data", "model")   — 256 chips
 multi-pod : (2, 16, 16) over ("pod", "data", "model") — 512 chips
 
 Functions, not module constants, so importing never touches jax device
-state. The dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512
-*before* any jax import (see dryrun.py); real TPU launches rely on the
-default device discovery.
+state; TPU launches rely on the default device discovery.
 """
 from __future__ import annotations
 

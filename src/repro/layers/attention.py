@@ -224,7 +224,7 @@ def decode_attention(
     (B,L)``) — the slot-paged serving pool decodes rows at independent
     positions — or be batch-free (legacy shared-position decode).
 
-    Perf notes (EXPERIMENTS.md §Perf iteration 2): the cache layout is
+    Perf notes: the cache layout is
     (B, KH, L, D) — the dot's native batch-major layout, so no per-step
     transpose copy of the cache; the scores dot runs in the cache dtype
     (contraction is over head_dim only — ≤256 terms — so bf16 accumulation

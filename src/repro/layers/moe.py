@@ -5,10 +5,9 @@ leading batch dim (which is data-sharded), each group routes its own tokens
 into a per-group expert buffer of static capacity, and expert compute is a
 single einsum over (groups, experts, capacity, d). Under GSPMD this keeps
 token gathers within their data shard and shards expert weights/compute on
-the 'model' axis (EP) with no explicit all-to-all — the collective pattern
-the dry-run analyzes. Overflowing tokens are dropped (capacity factor
-controls the drop rate), underfull slots are zero-padded — the standard
-GShard/Switch capacity semantics.
+the 'model' axis (EP) with no explicit all-to-all. Overflowing tokens
+are dropped (capacity factor controls the drop rate), underfull slots are
+zero-padded — the standard GShard/Switch capacity semantics.
 
 Supports: top-1 (Switch / llama4-maverick), top-k (granite top-8), optional
 shared expert (llama4), load-balancing auxiliary loss (Switch eq. 4).

@@ -285,7 +285,7 @@ def _apply_layer(
 
     if kind == "moe":
         h = apply_norm(cfg.norm, lp["norm2"], x)
-        # §Perf iteration 4: optionally gather the sequence dim at the MoE
+        # Optionally gather the sequence dim at the MoE
         # boundary — routing sorts and the (B,E,C,·) expert einsums otherwise
         # conflict with context-parallel seq sharding and XLA partial-sum
         # all-reduces expert-activation-sized tensors per layer. Helps ff-TP

@@ -41,10 +41,10 @@ size, slot or schedule.
 
 **Live-batch dispatch.** Each decode step consults the dispatch layer at
 the *live* batch size (:meth:`repro.api.FaustOp.dispatch_for`,
-``record=False``) so the backend choice — and the autotuned ``bt`` tile —
+``record=False``) so the backend choice — and the fitted ``bt`` tile —
 follows the batch as it breathes; the
-:class:`~repro.api.dispatch.DispatchReport` of each live batch size
-(including its autotune ``source``) is recorded on :class:`EngineStats`.
+:class:`~repro.api.dispatch.DispatchReport` of each live batch size is
+recorded on :class:`EngineStats`.
 :class:`LMExecutor` answers the query once per batch size and keeps the
 answer until the next :meth:`LMExecutor.swap_unembed`.
 
@@ -515,7 +515,7 @@ class LMExecutor:
         closures, a swap whose arrays keep their shapes/dtypes reuses the
         compiled caches untouched (values-only swap); changed support
         sizes retrace on the next call — the staged re-pack.  Policy
-        (classification, autotune invalidation, stats) lives in
+        (classification, guard, stats) lives in
         :mod:`repro.streaming.swap` — this is only the publication
         primitive.
         """

@@ -12,8 +12,8 @@ Coding" (arXiv:0908.0050), to PALM4MSA:
   and a budget controller choosing skip / incremental sweep / full
   hierarchical refactorization per step.
 * :mod:`repro.streaming.swap` — atomic operator hot-swap into the serving
-  runtime between decode steps (values-only swaps keep jit caches and
-  autotune hits; support changes re-pack and invalidate).
+  runtime between decode steps (values-only swaps keep jit caches;
+  support changes re-pack).
 """
 from repro.streaming.online import StreamingConfig, StreamingFaust, UpdateRecord
 from repro.streaming.swap import SwapReport, classify_swap, hot_swap

@@ -10,22 +10,17 @@ live :class:`~repro.runtime.engine.Engine` / ``Server`` / executor
 * **values-only swap** — the refreshed chain keeps the old support
   (identical ``in_idx``, identical shapes ⇒ identical ``ChainPlan``).
   Params are per-call arguments of the jitted closures, so the swap is a
-  pure host-side pointer flip: compiled caches, autotune table hits
-  (:func:`repro.api.autotune.key_of` contains no array values), and the
-  dispatch decision all stay valid.  In-flight requests simply see the
-  new values from their next step on — greedy decode of a request
-  admitted *after* the swap is token-exact vs a process that had the
-  refreshed chain from the start (pinned by ``tests/test_swap.py``).
+  pure host-side pointer flip: compiled caches and the dispatch decision
+  (a function of shapes alone) both stay valid.  In-flight requests
+  simply see the new values from their next step on — greedy decode of
+  a request admitted *after* the swap is token-exact vs a process that
+  had the refreshed chain from the start (pinned by
+  ``tests/test_swap.py``).
 * **staged re-pack** — the support moved (``in_idx`` values or shapes
   changed).  The next prefill/decode call with the new shapes retraces
   (that *is* the staged re-pack: ``pack_chain`` runs against the new
-  support at trace time), the executor's advisory op is rebuilt, and
-  measured autotune entries for the *old* signature are invalidated.
-  When the swap changes ``s_tot`` the old entries die naturally (the key
-  embeds ``s_tot``); when a support change happens to preserve ``s_tot``
-  the timings could silently survive despite e.g. different sharded
-  collective crossings — :func:`repro.api.autotune.invalidate` drops them
-  explicitly.
+  support at trace time) and the executor's advisory op is rebuilt, so
+  dispatch prices the new shapes.
 
 The swap itself is atomic at the scheduler's granularity: the engine is
 host-driven (``Engine.step()``), so calling :func:`hot_swap` between
@@ -79,7 +74,6 @@ class SwapReport:
     s_tot_before: int
     s_tot_after: int
     retrace: bool  # will the next engine step retrace its closures?
-    invalidated: int  # autotune entries explicitly dropped (repack only)
     # Quantized swaps only (defaults preserve the f32 report contract):
     requantized: bool = False  # new values re-quantized to the old layout
     # A values-only f32 swap is token-exact for post-swap requests by
@@ -238,8 +232,6 @@ def hot_swap(
     serving untouched, ``EngineStats.swap_rejects`` is bumped, and the
     report carries ``accepted=False`` + the reason.  ``None`` defers to
     ``REPRO_SWAP_GUARD`` (off by default), ``False`` disables."""
-    from repro.api import autotune
-
     ex = _executor_of(target)
     old = ex.unembed_blockfaust()
     if old is None:
@@ -255,21 +247,9 @@ def hot_swap(
             s_tot_before=int(old.s_tot),
             s_tot_after=int(new.s_tot),
             retrace=False,
-            invalidated=0,
             accepted=False,
             rel_err=rel_err,
             reject_reason=reject,
-        )
-    invalidated = 0
-    if kind == REPACK:
-        # Old-signature timings are stale.  s_tot change ⇒ the key moves
-        # and misses naturally; same-s_tot support moves need the explicit
-        # drop.  Invalidate unconditionally on repack — idempotent, and an
-        # s_tot-changing swap just finds nothing left under the old prefix.
-        from repro.api.operator import FaustOp
-
-        invalidated = autotune.invalidate(
-            autotune.op_key_prefix(FaustOp.from_blockfaust(old))
         )
     ex.swap_unembed(new)
     stats = getattr(target, "stats", None)  # Engine-level accounting
@@ -284,7 +264,6 @@ def hot_swap(
             fo.values.shape != fn.values.shape
             for fo, fn in zip(old.factors, new.factors)
         ),
-        invalidated=invalidated,
         rel_err=rel_err,
     )
 
@@ -321,9 +300,7 @@ def quantized_swap(
     Re-quantizes the refreshed chain ``new`` (f32 ``PackedChain`` or
     ``BlockFaust``) against ``old``'s existing layout and classifies the
     result: ``values_only`` when the support survived (same plan, same
-    ``in_idx``), ``repack`` otherwise (old-signature autotune entries are
-    invalidated, exactly as :func:`hot_swap` does — the ``|vq:`` key
-    component shares the invalidation prefix).  ``token_exact`` is True
+    ``in_idx``), ``repack`` otherwise.  ``token_exact`` is True
     only when requantization reproduced the old scales bit-for-bit; a
     scale that moved means the new chain rounds to different grid points
     than the one it replaces, so post-swap decodes are equivalent to a
@@ -336,8 +313,6 @@ def quantized_swap(
     vs the quantized incumbent; a rejected candidate returns ``(old,
     report)`` with ``accepted=False`` — the incumbent chain is handed
     back, so publishing the returned chain is always safe."""
-    from repro.api import autotune
-
     new_q = requantize_like(old, new)
     rel_err, reject = _guard_check(
         old, new_q, _guard_threshold(guard), n_probes, seed
@@ -348,7 +323,6 @@ def quantized_swap(
             s_tot_before=int(np.prod(old.values.shape)),
             s_tot_after=int(np.prod(old.values.shape)),
             retrace=False,
-            invalidated=0,
             requantized=True,
             token_exact=True,  # nothing published: the stream is untouched
             accepted=False,
@@ -358,14 +332,9 @@ def quantized_swap(
     if old.plan == new_q.plan and np.array_equal(
         np.asarray(old.in_idx), np.asarray(new_q.in_idx)
     ):
-        kind, invalidated = VALUES_ONLY, 0
+        kind = VALUES_ONLY
     else:
         kind = REPACK
-        from repro.api.operator import FaustOp
-
-        invalidated = autotune.invalidate(
-            autotune.op_key_prefix(FaustOp.from_packed(old))
-        )
     token_exact = kind == VALUES_ONLY and np.array_equal(
         np.asarray(old.scales), np.asarray(new_q.scales)
     )
@@ -374,7 +343,6 @@ def quantized_swap(
         s_tot_before=int(np.prod(old.values.shape)),
         s_tot_after=int(np.prod(new_q.values.shape)),
         retrace=kind == REPACK,
-        invalidated=invalidated,
         requantized=True,
         token_exact=token_exact,
         rel_err=rel_err,

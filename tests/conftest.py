@@ -4,21 +4,8 @@ Registers the ``slow`` marker (long-running / TPU-scale parametrizations)
 and skips those tests by default so bare-CPU runs stay fast — opt in with
 ``--runslow`` or ``RUN_SLOW=1``.  Everything here must work on a bare
 ``jax + pytest`` environment (no hypothesis, no TPU).
-
-Dispatch-decision tests assert which backend the roofline cost model
-picks, so the suite must price with the builtin host-independent
-constants even when this host has run ``scripts/calibrate_roofline.py``
-(whose cache ``launch/roofline.py`` would otherwise load at import, via
-the default path or an exported ``REPRO_ROOFLINE``) — pin the source
-unconditionally, before any ``repro`` import.
 """
 import os
-
-os.environ["REPRO_ROOFLINE"] = "builtin"
-# Same story for the measured autotune layer (repro.api.autotune): any
-# table this host has built must not steer backend="auto" assertions.
-# Autotune tests opt back in per-test with monkeypatch.
-os.environ["REPRO_AUTOTUNE"] = "off"
 
 import pytest
 

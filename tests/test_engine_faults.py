@@ -35,9 +35,9 @@ def _clean_quarantine():
     """Degraded-mode dispatch quarantines (signature, backend) pairs
     process-globally; never leak them into other tests."""
     yield
-    from repro.api import autotune
+    from repro.api import dispatch
 
-    autotune.clear_quarantine()
+    dispatch.clear_quarantine()
 
 
 def _prompt(rng, n, vocab=97):
@@ -421,7 +421,7 @@ def test_degraded_dispatch_demotes_once_and_quarantines(monkeypatch):
     import jax
 
     import repro.kernels.ops as kops
-    from repro.api import autotune, dispatch
+    from repro.api import dispatch
 
     jax.config.update("jax_platform_name", "cpu")
     monkeypatch.setenv("REPRO_DEGRADED", "1")  # degraded mode is opt-in
@@ -450,7 +450,7 @@ def test_degraded_dispatch_demotes_once_and_quarantines(monkeypatch):
     monkeypatch.setattr(kops, "packed_chain_apply", real)
     rep2 = op.dispatch_for(4, x.dtype)
     assert rep2.backend != "fused" and "fused" not in rep2.feasible
-    autotune.clear_quarantine()
+    dispatch.clear_quarantine()
     assert op.dispatch_for(4, x.dtype).backend == "fused"
 
 

@@ -314,7 +314,7 @@ def test_engine_vs_server_multi_codebook():
 def test_engine_live_batch_dispatch_reports():
     """A FAµST-parameterized model gets a DispatchReport at each *live*
     batch size it decoded at (advisory query: doesn't clobber
-    last_report), with the autotune source recorded; the executor prices
+    last_report), priced by the model; the executor prices
     each batch size once."""
     from repro.api import dispatch as _dispatch
     from repro.layers.faust_linear import FaustSpec
@@ -343,7 +343,7 @@ def test_engine_live_batch_dispatch_reports():
     for b, r in reps.items():
         assert r.batch == b and ex.dispatch_for(b) is r  # priced once
         assert r.backend in r.feasible
-        assert r.source == "model"  # conftest pins REPRO_AUTOTUNE=off
+        assert r.source == "model"
         assert r.bt >= 1
     # the engine's advisory queries are record=False: the process-level
     # last_report still holds a decision staged by a real apply

@@ -1,5 +1,5 @@
 """Pipeline parallelism over the 'pod' axis — subprocess tests (forced
-multi-device host platform, like the dry-run)."""
+multi-device host platform)."""
 import subprocess
 import sys
 import textwrap

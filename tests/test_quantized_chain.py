@@ -12,8 +12,6 @@ and the dequantizing kernels):
   * gradient parity (dx and dscales) through the dequantizing fused
     backward vs autodiff of the dequantizing reference walk;
   * sharded parity on a 2×2 debug mesh (skips below 4 devices);
-  * autotune key separation: a measured f32 table hit is never served to
-    the quantized variant of the same signature;
   * the quantized hot-swap: re-quantize against the serving layout,
     values-only vs repack classification, token-exactness only when the
     scales survived bit-for-bit.
@@ -26,7 +24,6 @@ import numpy as np
 import pytest
 
 from repro.api import FaustOp
-from repro.api import autotune as at
 from repro.core.compress import (
     BlockFaust,
     QUANT_DTYPES,
@@ -257,7 +254,7 @@ def test_sharded_quantized_parity(use_kernel):
 
 
 # ---------------------------------------------------------------------------
-# Dispatch + autotune
+# Dispatch
 # ---------------------------------------------------------------------------
 
 
@@ -273,31 +270,6 @@ def test_dispatch_prices_quantized_bytes():
     row = rq.as_row()
     assert row["weight_bytes"] == qc.weight_bytes
     assert row["values_dtype"] == "int8"
-
-
-def test_autotune_key_separates_quantized(tmp_path, monkeypatch):
-    """A measured f32 entry must never steer the quantized twin: the keys
-    differ by the |vq: component, so the quantized op misses the table and
-    falls back to the model."""
-    pc = _chain(15)
-    qc = quantize_chain(pc, "int8")
-    opf, opq = FaustOp.from_packed(pc), FaustOp.from_packed(qc)
-    kf = at.key_for_op(opf, batch=64, dtype=jnp.float32, grad=False,
-                       mesh_shape=None)
-    kq = at.key_for_op(opq, batch=64, dtype=jnp.float32, grad=False,
-                       mesh_shape=None)
-    assert kq == kf + "|vq:int8:per_block"
-    # same signature prefix: one hot-swap invalidation covers both
-    assert at.op_key_prefix(opf) == at.op_key_prefix(opq)
-    table = tmp_path / "autotune.json"
-    monkeypatch.setenv("REPRO_AUTOTUNE_TABLE", str(table))
-    monkeypatch.setenv("REPRO_AUTOTUNE", "")  # readonly: hits steer
-    at.record(kf, {"best": "dense", "us": {"dense": 1.0, "fused": 9.9}})
-    at.reload()
-    rf = opf.dispatch_for(64)
-    rq = opq.dispatch_for(64)
-    assert rf.source == "measured" and rf.backend == "dense"
-    assert rq.source == "model"  # f32 hit NOT served to the int8 op
 
 
 # ---------------------------------------------------------------------------
@@ -327,20 +299,11 @@ def test_quantized_swap_values_only_and_token_exactness():
         requantize_like(qc, new_q)  # refreshed chain already quantized
 
 
-def test_quantized_swap_repack_invalidates(tmp_path, monkeypatch):
+def test_quantized_swap_moved_support_repacks():
     from repro.streaming.swap import quantized_swap
 
     pc = _chain(17, (4, 4))
     qc = quantize_chain(pc, "int8")
-    table = tmp_path / "autotune.json"
-    monkeypatch.setenv("REPRO_AUTOTUNE_TABLE", str(table))
-    monkeypatch.setenv("REPRO_AUTOTUNE", "")
-    key = at.key_for_op(
-        FaustOp.from_packed(qc), batch=64, dtype=jnp.float32, grad=False,
-        mesh_shape=None,
-    )
-    at.record(key, {"best": "fused", "us": {"fused": 1.0}})
-    at.reload()
     # moved support, same s_tot: shuffle each factor's in_idx
     idx = np.asarray(pc.in_idx).copy()
     o0, o1 = pc.plan.offsets[0], pc.plan.offsets[1]
@@ -353,8 +316,6 @@ def test_quantized_swap_repack_invalidates(tmp_path, monkeypatch):
     new_q, rep = quantized_swap(qc, moved)
     assert rep.kind == "repack" and rep.retrace
     assert not rep.token_exact
-    assert rep.invalidated == 1  # the |vq: entry died with the prefix
-    assert at.lookup(key) is None
 
 
 def test_faustop_roundtrip_preserves_quantization():

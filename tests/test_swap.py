@@ -8,11 +8,7 @@ The load-bearing claims, each pinned here:
     after it — differential test against an engine that had the refreshed
     chain from the start;
   * a re-pack swap keeps serving (staged retrace) and ``dispatch_for``
-    reports the new chain truthfully;
-  * autotune invariants: values-only swaps keep measured table hits
-    (the key has no array values), support/shape changes re-price —
-    naturally when ``s_tot`` moves the key, via explicit
-    :func:`repro.api.autotune.invalidate` when it doesn't.
+    reports the new chain truthfully.
 """
 import dataclasses
 import functools
@@ -22,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api import FaustOp, autotune
+from repro.api import FaustOp
 from repro.api import dispatch as dispatch_mod
 from repro.configs import get_smoke
 from repro.core.compress import BlockFaust, random_block_factor
@@ -186,98 +182,3 @@ def test_server_swap_unembed():
         np.asarray(srv.unembed_blockfaust().factors[0].values),
         np.asarray(old.factors[0].values) * 1.5, rtol=1e-6,
     )
-
-
-# --- autotune invariants (satellite b) --------------------------------------
-
-
-@pytest.fixture
-def table(tmp_path, monkeypatch):
-    path = str(tmp_path / "autotune.json")
-    monkeypatch.setenv("REPRO_AUTOTUNE_TABLE", path)
-    monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)  # readonly mode
-    autotune.reload()
-    yield path
-    autotune.reload()
-
-
-def _measured_entry():
-    return {"best": "bsr", "us": {"bsr": 3.0, "fused": 7.0, "dense": 50.0},
-            "bt": 16}
-
-
-def test_values_only_swap_keeps_measured_hits(table):
-    op = FaustOp.wrap(_chain())
-    key = autotune.key_for_op(
-        op, batch=16, dtype=jnp.float32, grad=False, mesh_shape=None
-    )
-    autotune.record(key, _measured_entry())
-    rep = dispatch_mod.dispatch(op, 16, jnp.float32)
-    assert rep.source == "measured" and rep.backend == "bsr"
-
-    # values-only refresh: same signature → same key → hit survives
-    op2 = FaustOp.wrap(_perturb_values(_chain()))
-    rep2 = dispatch_mod.dispatch(op2, 16, jnp.float32)
-    assert rep2.source == "measured" and rep2.backend == "bsr"
-
-    # different k: s_tot moves the key → truthful model fallback
-    op3 = FaustOp.wrap(_chain(k=3))
-    rep3 = dispatch_mod.dispatch(op3, 16, jnp.float32)
-    assert rep3.source == "model"
-    assert "measured" not in rep3.reason
-
-
-def test_support_move_invalidates_and_reprices(table):
-    op = FaustOp.wrap(_chain())
-    for b in (16, 32):
-        autotune.record(
-            autotune.key_for_op(
-                op, batch=b, dtype=jnp.float32, grad=False, mesh_shape=None
-            ),
-            _measured_entry(),
-        )
-    assert dispatch_mod.dispatch(op, 16, jnp.float32).source == "measured"
-
-    # same-s_tot support move: the key would NOT move — explicit drop
-    moved = _move_support(_chain())
-    op_moved = FaustOp.wrap(moved)
-    assert op_moved.s_tot == op.s_tot
-    n = autotune.invalidate(autotune.op_key_prefix(op))
-    assert n == 2
-    rep = dispatch_mod.dispatch(op_moved, 16, jnp.float32)
-    assert rep.source == "model"  # re-prices from the model, truthfully
-
-
-def test_hot_swap_repack_invalidates_old_signature(table):
-    """End to end: a re-pack hot-swap drops the old chain's measured
-    entries via ``op_key_prefix`` and the report accounts them."""
-    eng = _engine()
-    old = eng.executor.unembed_blockfaust()
-    old_op = FaustOp.from_blockfaust(old)
-    keys = [
-        autotune.key_for_op(
-            old_op, batch=b, dtype=jnp.float32, grad=False, mesh_shape=None
-        )
-        for b in (1, 2)
-    ]
-    for key in keys:
-        autotune.record(key, _measured_entry())
-    cfg3, params3 = _model(k=3)
-    new = LMExecutor(cfg3, params3, max_len=24, n_slots=2).unembed_blockfaust()
-    report = hot_swap(eng, new)
-    assert report.kind == "repack"
-    assert report.invalidated == 2
-    for key in keys:
-        assert autotune.lookup(key) is None
-
-    # a values-only swap leaves the (new chain's) entries alone
-    key_new = autotune.key_for_op(
-        FaustOp.from_blockfaust(new), batch=1, dtype=jnp.float32,
-        grad=False, mesh_shape=None,
-    )
-    autotune.record(key_new, _measured_entry())
-    report2 = hot_swap(eng, _perturb_values(new))
-    assert report2.kind == "values_only"
-    assert report2.invalidated == 0
-    assert autotune.lookup(key_new) is not None
-    assert eng.stats.swaps == 2
